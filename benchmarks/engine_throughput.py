@@ -479,6 +479,8 @@ def host_rows(quick: bool = False):
 
 
 def main():
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small rounds, K<=64, no overlap rows (CI smoke)")

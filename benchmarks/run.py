@@ -31,6 +31,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (agg_throughput, engine_throughput,
                             fig6_response_time, fig7_breakdown,
                             fig8_accuracy, fig8_convergence, roofline)

@@ -16,8 +16,9 @@ per-drain dispatch (DESIGN.md §3).
 ``--shards N`` additionally demuxes the compiled drain schedule over N
 worker-mesh shards, each folding a per-shard partial sum combined at
 END — the paper's per-core layout (DESIGN.md §7), still bitwise
-identical.  With fewer than N devices a single-device emulation runs;
-to see the real mesh:
+identical.  On the CPU, with fewer than N devices a single-device
+emulation runs (an accelerator with too few devices is an error); to
+see the real mesh:
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 ``--hosts N`` runs the hierarchical engine (DESIGN.md §12): clients
@@ -26,7 +27,8 @@ its own sessions into shard-local partials, and one psum per mesh
 level (worker within a host, then host across hosts) produces the
 global — verified bitwise against both the flat compiled round and the
 eager per-host twin ``run_hier_round``.  Composes with ``--shards``;
-with fewer than N*shards devices a single-device emulation runs.
+on the CPU, with fewer than N*shards devices a single-device emulation
+runs.
 
 ``--deadline [N]`` makes client 0 a permanent straggler: its last
 packets and its END trail the round deadline, the server times it out
@@ -77,6 +79,7 @@ from repro.core.packets import packetize
 from repro.core.rounds import losses_only_twin, make_straggler_stream
 from repro.core.server import (EngineConfig, make_uplink_stream,
                                run_engine_round)
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def straggler_demo(args):
@@ -364,6 +367,7 @@ def hier_demo(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--compile", action="store_true",
                     help="run each round as one compiled lax.scan "

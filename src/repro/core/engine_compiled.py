@@ -785,14 +785,35 @@ def dispatch_round(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     ``cfg.shards > 1`` demuxes the schedule per shard and routes the
     scan through the sharded partial-sum path: over a real ``'worker'``
     mesh when the platform has enough devices
-    (``runtime.sharding.worker_mesh``), else a bitwise single-device
-    emulation.  ``cfg.hosts > 1`` first partitions the arrivals by
-    client-range ownership (``partition_schedule_by_host``), re-demuxes
-    each host's stream with its own rings, shard-splits within each
-    host, and routes through the two-level psum fold over the 2-D
-    ``('host', 'worker')`` mesh (``runtime.sharding.host_worker_mesh``)
-    — or its bitwise nested-vmap emulation (DESIGN.md §12).
+    (``runtime.sharding.worker_mesh``), else — on the CPU only — a
+    bitwise single-device emulation.  ``cfg.hosts > 1`` first partitions
+    the arrivals by client-range ownership
+    (``partition_schedule_by_host``), re-demuxes each host's stream with
+    its own rings, shard-splits within each host, and routes through the
+    two-level psum fold over the 2-D ``('host', 'worker')`` mesh
+    (``runtime.sharding.host_worker_mesh``) — or its bitwise
+    nested-vmap emulation (DESIGN.md §12).
     """
+    fn, args, kwargs = _round_call(cfg, sched, total, counts, prev_global,
+                                   client_flats, down_mask, mix_alpha)
+    return fn(*args, **kwargs)
+
+
+def lower_round(cfg: EngineConfig, sched: DrainSchedule, total, counts,
+                prev_global, client_flats=None, down_mask=None,
+                mix_alpha: float = 0.0) -> jax.stages.Lowered:
+    """The round ``dispatch_round`` would run, lowered and not run: its
+    text shows which fold body and which collectives the round holds
+    (a Pallas kernel lowers to ``tpu_custom_call``, a mesh combine to
+    an all-reduce)."""
+    fn, args, kwargs = _round_call(cfg, sched, total, counts, prev_global,
+                                   client_flats, down_mask, mix_alpha)
+    return fn.lower(*args, **kwargs)
+
+
+def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
+                prev_global, client_flats, down_mask, mix_alpha: float):
+    """(jitted round, args, static kwargs) for one round's schedule."""
     if cfg.mode not in ("exact", "approx"):
         raise ValueError(cfg.mode)
     robust_table = cfg.agg_mode in ("trimmed_mean", "median")
@@ -820,34 +841,28 @@ def dispatch_round(cfg: EngineConfig, sched: DrainSchedule, total, counts,
             idx, w, pk, sc, _ = shard_schedule(sched, cfg.shards)
             ctx = worker_ctx(cfg.shards)
             mesh = None if ctx is None else ctx.mesh
+    def opt(a):
+        return None if a is None else jnp.asarray(a)
+
+    common = dict(payload=cfg.payload, n_params=cfg.n_params,
+                  use_pallas=_use_pallas(cfg), block_slots=8,
+                  block_pkts=min(BLOCK_PKTS, idx.shape[-1]),
+                  mix_alpha=float(mix_alpha), interpret=_interpret(),
+                  shards=cfg.shards, hosts=cfg.hosts, mesh=mesh)
     if robust_table:
-        return _robust_round_device(
-            jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk),
-            None if sc is None else jnp.asarray(sc),
-            jnp.asarray(prev_global),
-            None if client_flats is None else jnp.asarray(client_flats),
-            None if down_mask is None else jnp.asarray(down_mask),
-            payload=cfg.payload, n_params=cfg.n_params,
-            n_slots=cfg.n_slots, n_clients=cfg.n_clients,
-            use_pallas=_use_pallas(cfg), block_slots=8,
-            block_pkts=min(BLOCK_PKTS, idx.shape[-1]),
-            mix_alpha=float(mix_alpha), interpret=_interpret(),
-            median=(cfg.agg_mode == "median"), beta=float(cfg.trim_beta),
-            shards=cfg.shards, hosts=cfg.hosts, mesh=mesh)
-    return _round_device(
+        return _robust_round_device, (
+            jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk), opt(sc),
+            jnp.asarray(prev_global), opt(client_flats), opt(down_mask),
+        ), dict(common, n_slots=cfg.n_slots, n_clients=cfg.n_clients,
+                median=(cfg.agg_mode == "median"),
+                beta=float(cfg.trim_beta))
+    return _round_device, (
         jnp.asarray(total, jnp.float32), jnp.asarray(counts, jnp.float32),
-        jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk),
-        None if sc is None else jnp.asarray(sc),
-        jnp.asarray(prev_global),
-        None if client_flats is None else jnp.asarray(client_flats),
-        None if down_mask is None else jnp.asarray(down_mask),
-        mode=cfg.mode, payload=cfg.payload, n_params=cfg.n_params,
-        use_pallas=_use_pallas(cfg), block_slots=8,
-        block_pkts=min(BLOCK_PKTS, idx.shape[-1]),
-        mix_alpha=float(mix_alpha), interpret=_interpret(),
-        agg_clip=(cfg.agg_mode == "norm_clip"),
-        clip_tau=float(cfg.clip_tau), shards=cfg.shards, hosts=cfg.hosts,
-        mesh=mesh)
+        jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk), opt(sc),
+        jnp.asarray(prev_global), opt(client_flats), opt(down_mask),
+    ), dict(common, mode=cfg.mode,
+            agg_clip=(cfg.agg_mode == "norm_clip"),
+            clip_tau=float(cfg.clip_tau))
 
 
 # ---------------------------------------------------------------------------

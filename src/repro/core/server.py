@@ -87,7 +87,8 @@ class EngineConfig:
     # worker-mesh shards for the compiled round (DESIGN.md §7): each
     # shard folds its worker rings' drains into a per-shard partial sum
     # combined at END — the paper's per-core layout.  Effective device
-    # parallelism is min(shards, n_workers, available devices); any
+    # parallelism is min(shards, n_workers); a CPU with fewer devices
+    # runs the fold emulated, an accelerator with fewer raises.  Any
     # shard count is bitwise identical on integer payloads.
     shards: int = 1
     # hierarchical leaf hosts for the compiled round (DESIGN.md §12):

@@ -14,8 +14,9 @@ divided + zero-masked on the last client-block.  Mosaic's automatic
 double buffering is still the RX→worker→TX pipeline — the DMA of client
 block k+1 overlaps the accumulate of block k — but VMEM per step is now
 ``(BK, BC, W)`` **independent of K**, so the kernel scales to thousands
-of clients (K=1024, BK=8, BC=8, W=512 → 128 KiB payloads vs ~17 MB for
-the old all-K layout, which exceeded the ~16 MB VMEM budget).
+of clients (K=1024, BK=8, BC=128, W=512 → 2 MiB payloads vs ~17 MB for
+the old all-K layout, which exceeded the ~16 MB VMEM budget).  BC is
+128 because the (BK, BC) mask block puts chunks on the lanes.
 
 ``finalize=False`` skips the divide and returns raw (sum, counts) — the
 host-level streaming pipeline (core/pipeline.py) uses it to fold client
@@ -57,7 +58,7 @@ def _fedavg_accum_kernel(x_ref, m_ref, out_ref, cnt_ref, *, finalize: bool):
 
 
 def fedavg_accum_pallas(packets: jnp.ndarray, wmask: jnp.ndarray,
-                        *, block_clients: int = 8, block_chunks: int = 8,
+                        *, block_clients: int = 8, block_chunks: int = 128,
                         finalize: bool = True,
                         interpret: bool = False):
     """packets (K, C, W) any float dtype; wmask (K, C) f32.
@@ -69,6 +70,9 @@ def fedavg_accum_pallas(packets: jnp.ndarray, wmask: jnp.ndarray,
     """
     K, C, W = packets.shape
     assert K % block_clients == 0, (K, block_clients)
+    # the (K, C) mask blocks put chunks on the lanes, so Mosaic needs a
+    # chunk block of 128 lanes or the whole chunk axis
+    block_chunks = min(block_chunks, C)
     assert C % block_chunks == 0, (C, block_chunks)
     grid = (C // block_chunks, K // block_clients)
     kernel = functools.partial(_fedavg_accum_kernel, finalize=finalize)

@@ -47,7 +47,7 @@ def _pad_axis(arrs, size: int, block: int, axis: int):
                    static_argnames=("block_clients", "block_chunks",
                                     "finalize"))
 def fedavg_accum(packets, wmask, block_clients: int = 8,
-                 block_chunks: int = 8, finalize: bool = True):
+                 block_chunks: int = 128, finalize: bool = True):
     """(K, C, W) payloads + (K, C) weighted mask -> (avg (C, W), counts (C,)).
 
     With ``finalize=False`` the first output is the raw masked sum
@@ -68,7 +68,7 @@ def fedavg_accum(packets, wmask, block_clients: int = 8,
                    static_argnames=("block_clients", "block_chunks",
                                     "finalize"))
 def quantized_accum(q, scales, wmask, block_clients: int = 8,
-                    block_chunks: int = 8, finalize: bool = True):
+                    block_chunks: int = 128, finalize: bool = True):
     """int8 (K, C, W) + scales/mask (K, C) -> (avg (C, W), counts (C,))."""
     K, C, W = q.shape
     q, scales, wmask = _pad_axis([q, scales, wmask], K, block_clients, 0)
@@ -85,7 +85,7 @@ def quantized_accum(q, scales, wmask, block_clients: int = 8,
                    static_argnames=("block_clients", "block_chunks"),
                    donate_argnums=(0, 1))
 def fedavg_accum_into(total, counts, packets, wmask,
-                      block_clients: int = 8, block_chunks: int = 8):
+                      block_clients: int = 8, block_chunks: int = 128):
     """Streaming fold: (total (C, W), counts (C,)) += raw masked sums.
 
     The accumulator pair is *donated* (``donate_argnums``), so the

@@ -56,6 +56,11 @@ from jax.experimental.pallas import tpu as pltpu
 # jitted wrapper sees few distinct shapes
 BLOCK_PKTS = 128
 
+# the one-hot routing products must not round payloads: on TPU a default
+# f32 dot runs one bf16 pass (8 mantissa bits), so both the kernel and
+# its jnp twin ask for full f32.  CPU backends compute f32 either way.
+_F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def _packet_scatter_kernel(idx_ref, pkt_ref, init_ref, out_ref):
     del idx_ref, init_ref     # idx is consumed by the BlockSpec index maps
@@ -78,24 +83,31 @@ def packet_scatter_pallas(packets: jnp.ndarray, idx: jnp.ndarray,
     N, W = packets.shape
     if init is None:
         init = jnp.zeros((n_slots, W), packets.dtype)
-    # init rides along only to donate its buffer (input_output_aliases);
-    # its block is never read, so a constant index map lets Pallas fetch
-    # it once instead of one discarded (1, W) DMA per packet
+    # one packet row per grid step, carried on a trailing unit axis: a
+    # (1, 1, W) block spans the full extent of its last two dimensions,
+    # which Mosaic requires of a row that is neither 8 sublanes nor the
+    # whole array.  init rides along only to donate its buffer
+    # (input_output_aliases); its block is never read, so a constant
+    # index map lets Pallas fetch it once instead of one discarded row
+    # DMA per packet
+    row = (None, 1, W)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(N,),
-        in_specs=[pl.BlockSpec((1, W), lambda i, idx_ref: (i, 0)),
-                  pl.BlockSpec((1, W), lambda i, idx_ref: (0, 0))],
-        out_specs=pl.BlockSpec((1, W), lambda i, idx_ref: (idx_ref[i], 0)),
+        in_specs=[pl.BlockSpec(row, lambda i, idx_ref: (i, 0, 0)),
+                  pl.BlockSpec(row, lambda i, idx_ref: (0, 0, 0))],
+        out_specs=pl.BlockSpec(row, lambda i, idx_ref: (idx_ref[i], 0, 0)),
     )
     return pl.pallas_call(
         _packet_scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, W), packets.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_slots, 1, W), packets.dtype),
         # operand indices count the scalar-prefetch arg: 0=idx, 1=packets
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx.astype(jnp.int32), packets, init.astype(packets.dtype))
+    )(idx.astype(jnp.int32), packets.reshape(N, 1, W),
+      init.astype(packets.dtype).reshape(n_slots, 1, W)
+      ).reshape(n_slots, W)
 
 
 def _scatter_accum_body(idx_ref, w_ref, pkt, acc_in_ref, cnt_in_ref,
@@ -127,7 +139,7 @@ def _scatter_accum_body(idx_ref, w_ref, pkt, acc_in_ref, cnt_in_ref,
     cnt_ref[...] += jnp.sum(whot, axis=1, keepdims=True)
 
     if exact:
-        acc_ref[...] += jnp.dot(whot, pkt,
+        acc_ref[...] += jnp.dot(whot, pkt, precision=_F32_DOT,
                                 preferred_element_type=jnp.float32)
     else:
         # Lock-free race, deterministic form: each writer reads the
@@ -140,6 +152,7 @@ def _scatter_accum_body(idx_ref, w_ref, pkt, acc_in_ref, cnt_in_ref,
                           keepdims=True)              # (BS, 1); 0 = no hit
         lasthot = (colpos == lastcol) & valid
         contrib = jnp.dot(lasthot.astype(jnp.float32) * w, pkt,
+                          precision=_F32_DOT,
                           preferred_element_type=jnp.float32)
         acc_ref[...] = jnp.where(lastcol > 0, acc_in_ref[...] + contrib,
                                  acc_ref[...])
@@ -400,29 +413,36 @@ def robust_finalize_jnp(table: jnp.ndarray, pres: jnp.ndarray, *,
     return agg, m
 
 
-def _robust_finalize_kernel(tab_ref, pres_ref, agg_ref, m_ref, *,
+def _robust_finalize_kernel(tab_ref, pres_ref, agg_ref, m_ref, vm_ref, *,
                             median: bool, beta: float):
-    """Grid-step body of the fused robust finalize (one slot block).
+    """Grid-step body of the fused robust finalize (one slot block by
+    one lane tile).
 
     Rank selection without a sort: element (s, k, w)'s rank is the
     number of present values below it (ties broken by client order), a
-    K-step ``fori_loop`` of (BS, K, W) compares on the VPU — Mosaic has
+    K-step ``fori_loop`` of (BS, K, WL) compares on the VPU — Mosaic has
     no in-kernel sort, and the rank pass selects the identical value
     multiset, so for exactly-representable sums the result is bitwise
     equal to the sorted jnp twin (the same caveat as the scatter
-    kernels vs their twins).
+    kernels vs their twins).  The masked block is staged in the VMEM
+    scratch ``vm_ref`` and each step loads client row j from there:
+    Mosaic lowers a dynamic sublane slice of a ref, not of a value.
     """
-    v = tab_ref[...]                                      # (BS, K, W)
-    pres = pres_ref[...] > 0                              # (BS, K)
+    v = tab_ref[...]                                      # (BS, K, WL)
+    pf = pres_ref[...]                                    # (BS, K)
+    pres = pf > 0
     K = v.shape[1]
-    p3 = pres[:, :, None]
+    # expand the f32 presence, not the mask: Mosaic has no shape cast
+    # for a boolean (BS, K) -> (BS, K, 1)
+    p3 = pf[:, :, None] > 0
     m = jnp.sum(pres.astype(jnp.float32), axis=1)         # (BS,)
     big = jnp.float32(jnp.finfo(jnp.float32).max)
     vm = jnp.where(p3, v, big)
+    vm_ref[...] = vm
     kiota = jax.lax.broadcasted_iota(jnp.int32, vm.shape, 1)
 
     def rank_step(j, rank):
-        vj = jax.lax.dynamic_slice_in_dim(vm, j, 1, axis=1)  # (BS,1,W)
+        vj = vm_ref[:, pl.ds(j, 1), :]                    # (BS, 1, WL)
         below = (vj < vm) | ((vj == vm) & (j < kiota))
         return rank + below.astype(jnp.float32)
 
@@ -445,30 +465,36 @@ def robust_finalize_pallas(table: jnp.ndarray, pres: jnp.ndarray, *,
 
     table (S, K, W) f32 per-slot client table; pres (S, K) f32
     presence.  S must be a multiple of ``block_slots`` (callers pad
-    with inert zero slots).  Grid over slot blocks; each step holds its
-    (BS, K, W) table block in VMEM, rank-selects the trimmed band per
-    coordinate and averages it — no (S, K, W) intermediate ever leaves
-    VMEM.  Returns ``(agg (S, W), m (S,))`` like the jnp twin.
+    with inert zero slots).  Grid over (slot block, 128-lane tile);
+    each step holds its (BS, K, 128) table block in VMEM, rank-selects
+    the trimmed band per coordinate and averages it — no (S, K, W)
+    intermediate ever leaves VMEM, and the block's VMEM footprint
+    stays within budget up to K = 256.  Coordinates are independent,
+    so the last, partial lane tile (W = 367 is not a multiple of 128)
+    needs no padded copy of the table: its out-of-range lanes are never
+    written back.  Returns ``(agg (S, W), m (S,))`` like the jnp twin.
     """
     S, K, W = table.shape
     assert S % block_slots == 0, (S, block_slots)
+    lanes = min(W, 128)
     kernel = functools.partial(_robust_finalize_kernel, median=median,
                                beta=beta)
     agg, m = pl.pallas_call(
         kernel,
-        grid=(S // block_slots,),
+        grid=(S // block_slots, pl.cdiv(W, lanes)),
         in_specs=[
-            pl.BlockSpec((block_slots, K, W), lambda s: (s, 0, 0)),
-            pl.BlockSpec((block_slots, K), lambda s: (s, 0)),
+            pl.BlockSpec((block_slots, K, lanes), lambda s, l: (s, 0, l)),
+            pl.BlockSpec((block_slots, K), lambda s, l: (s, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_slots, W), lambda s: (s, 0)),
-            pl.BlockSpec((block_slots, 1), lambda s: (s, 0)),
+            pl.BlockSpec((block_slots, lanes), lambda s, l: (s, l)),
+            pl.BlockSpec((block_slots, 1), lambda s, l: (s, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((S, W), jnp.float32),
             jax.ShapeDtypeStruct((S, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((block_slots, K, lanes), jnp.float32)],
         interpret=interpret,
     )(table.astype(jnp.float32), pres.astype(jnp.float32))
     return agg, m[:, 0]
@@ -503,7 +529,8 @@ def packet_scatter_accum_batch_jnp(packets: jnp.ndarray, idx: jnp.ndarray,
     counts = counts + jnp.sum(whot, axis=1, keepdims=True)
     pkt = packets.astype(jnp.float32)
     if exact:
-        acc = acc + jnp.dot(whot, pkt, preferred_element_type=jnp.float32)
+        acc = acc + jnp.dot(whot, pkt, precision=_F32_DOT,
+                            preferred_element_type=jnp.float32)
     else:
         valid = hits & (w > 0)
         colpos = jax.lax.broadcasted_iota(jnp.int32, (S, N), 1) + 1
@@ -511,6 +538,7 @@ def packet_scatter_accum_batch_jnp(packets: jnp.ndarray, idx: jnp.ndarray,
                           keepdims=True)                  # (S, 1); 0 = no hit
         lasthot = (colpos == lastcol) & valid
         contrib = jnp.dot(lasthot.astype(jnp.float32) * w, pkt,
+                          precision=_F32_DOT,
                           preferred_element_type=jnp.float32)
         # ``acc`` here is the call-entry snapshot, so this reproduces
         # the kernel's deterministic lock-free race exactly
@@ -697,8 +725,11 @@ def packet_scatter_accum_sharded(sched_idx: jnp.ndarray,
     zero_acc = jnp.zeros_like(acc)
     zero_cnt = jnp.zeros_like(counts)
     q8 = sched_scales is not None
+    # check_vma=False: the scan carries start from the replicated zeros
+    # and leave varying per shard, which shard_map's varying-axis typing
+    # rejects as a carry type change; the psum makes the outputs
+    # replicated again, as out_specs=P() says
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(axis_name)
@@ -709,19 +740,20 @@ def packet_scatter_accum_sharded(sched_idx: jnp.ndarray,
                             sched_scales=bsc[0])
                 return combine_partials(a, c, axis_name=axis_name)
 
-            a, c = shard_map(
+            a, c = jax.shard_map(
                 shard_fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                out_specs=(P(), P()))(sched_idx, sched_w, sched_scales,
-                                      sched_pk)
+                out_specs=(P(), P()), check_vma=False,
+            )(sched_idx, sched_w, sched_scales, sched_pk)
         else:
             def shard_fn(bidx, bw, bpk):
                 # leading shard axis is size 1 on each device
                 a, c = body(bidx[0], bw[0], bpk[0], zero_acc, zero_cnt)
                 return combine_partials(a, c, axis_name=axis_name)
 
-            a, c = shard_map(
+            a, c = jax.shard_map(
                 shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=(P(), P()))(sched_idx, sched_w, sched_pk)
+                out_specs=(P(), P()), check_vma=False,
+            )(sched_idx, sched_w, sched_pk)
     elif q8:
         a_parts, c_parts = jax.vmap(
             lambda bidx, bw, bsc, bpk: body(bidx, bw, bpk, zero_acc,
@@ -777,8 +809,11 @@ def packet_scatter_accum_hier(sched_idx: jnp.ndarray,
     zero_acc = jnp.zeros_like(acc)
     zero_cnt = jnp.zeros_like(counts)
     q8 = sched_scales is not None
+    # check_vma=False: the scan carries start from the replicated zeros
+    # and leave varying per shard, which shard_map's varying-axis typing
+    # rejects as a carry type change; the psum makes the outputs
+    # replicated again, as out_specs=P() says
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(host_axis, worker_axis)
@@ -790,19 +825,20 @@ def packet_scatter_accum_hier(sched_idx: jnp.ndarray,
                             zero_cnt, sched_scales=bsc[0, 0])
                 return combine_partials(a, c, axis_name=levels)
 
-            a, c = shard_map(
+            a, c = jax.shard_map(
                 shard_fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                out_specs=(P(), P()))(sched_idx, sched_w, sched_scales,
-                                      sched_pk)
+                out_specs=(P(), P()), check_vma=False,
+            )(sched_idx, sched_w, sched_scales, sched_pk)
         else:
             def shard_fn(bidx, bw, bpk):
                 a, c = body(bidx[0, 0], bw[0, 0], bpk[0, 0], zero_acc,
                             zero_cnt)
                 return combine_partials(a, c, axis_name=levels)
 
-            a, c = shard_map(
+            a, c = jax.shard_map(
                 shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=(P(), P()))(sched_idx, sched_w, sched_pk)
+                out_specs=(P(), P()), check_vma=False,
+            )(sched_idx, sched_w, sched_pk)
     elif q8:
         fold = jax.vmap(jax.vmap(
             lambda bidx, bw, bsc, bpk: body(bidx, bw, bpk, zero_acc,

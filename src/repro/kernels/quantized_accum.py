@@ -47,11 +47,14 @@ def _quantized_accum_kernel(q_ref, s_ref, m_ref, out_ref, cnt_ref,
 
 def quantized_accum_pallas(q: jnp.ndarray, scales: jnp.ndarray,
                            wmask: jnp.ndarray, *, block_clients: int = 8,
-                           block_chunks: int = 8, finalize: bool = True,
+                           block_chunks: int = 128, finalize: bool = True,
                            interpret: bool = False):
     """q (K, C, W) int8; scales, wmask (K, C) f32 -> (avg (C,W), counts (C,1))."""
     K, C, W = q.shape
     assert K % block_clients == 0, (K, block_clients)
+    # the (K, C) mask blocks put chunks on the lanes, so Mosaic needs a
+    # chunk block of 128 lanes or the whole chunk axis
+    block_chunks = min(block_chunks, C)
     assert C % block_chunks == 0, (C, block_chunks)
     grid = (C // block_chunks, K // block_clients)
     kernel = functools.partial(_quantized_accum_kernel, finalize=finalize)

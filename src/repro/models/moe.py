@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -211,12 +210,12 @@ def apply_moe(p, x, cfg: ModelConfig, ctx: Optional[ParallelCtx]):
             cap = _capacity(t_local, cfg, ctx.moe_capacity_factor)
             fn = functools.partial(_moe_shard, cfg=cfg, capacity=cap,
                                    fsdp_axis=hspec)
-        out = shard_map(
+        out = jax.shard_map(
             fn, mesh=ctx.mesh,
             in_specs=(w_specs[0], w_specs[1], w_specs[2],
                       P(dp_spec, None, None), P(dp_spec, None, None)),
             out_specs=P(dp_spec, None, None),
-            check_rep=False,
+            check_vma=False,
         )(p["w1"], w3 if w3 is not None else jnp.zeros((), x.dtype),
           p["w2"], x, gate_mat)
 

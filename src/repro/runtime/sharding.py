@@ -26,9 +26,11 @@ batch — every model-parallel knob above is off in their ``ParallelCtx``):
 Invariants the tests pin (tests/test_engine_sharded.py,
 tests/test_engine_hier.py):
 
-- both factories return ``None`` below the device count, and the
-  engines then run a vmap emulation of the identical dataflow —
-  *bitwise* the same as the mesh path;
+- on the CPU both factories return ``None`` below the device count,
+  and the engines then run a vmap emulation of the identical dataflow —
+  *bitwise* the same as the mesh path; on an accelerator they raise
+  instead (``_too_few_devices``), so a sharded round never runs on one
+  chip without saying so;
 - the ownership ranges ``[h*K//H, (h+1)*K//H)`` tile the client set
   exactly (a partition: every client owned once) and are balanced to
   within one client;
@@ -133,18 +135,32 @@ HOST_AXIS = "host"
 def worker_mesh(n_shards: int) -> Optional[Mesh]:
     """1-D ``('worker',)`` mesh over the first ``n_shards`` devices.
 
-    Returns None when the platform exposes fewer devices than shards
-    (e.g. single-device CPU): callers fall back to a single-device
-    emulation of the same partial-sum dataflow, which is bitwise
-    identical — CI's multi-device lane runs the real mesh under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+    Returns None when a CPU platform exposes fewer devices than shards:
+    callers fall back to a single-device emulation of the same
+    partial-sum dataflow, which is bitwise identical — CI's multi-device
+    lane runs the real mesh under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.  On an
+    accelerator too few devices is an error, not an emulation.
     """
     if n_shards <= 1:
         return None
     devices = jax.devices()
     if len(devices) < n_shards:
-        return None
+        return _too_few_devices(devices, n_shards, f"shards={n_shards}")
     return Mesh(np.asarray(devices[:n_shards]), (WORKER_AXIS,))
+
+
+def _too_few_devices(devices, needed: int, ask: str) -> None:
+    """The CPU emulates a mesh it lacks (returns None); an accelerator
+    raises, because an emulated fold would hide that the round ran on
+    fewer chips than the configuration asks for."""
+    platform = devices[0].platform
+    if platform == "cpu":
+        return None
+    raise ValueError(
+        f"EngineConfig({ask}) needs {needed} {platform} devices, this "
+        f"process sees {len(devices)}; the single-device emulation of a "
+        f"sharded round runs only on the CPU")
 
 
 def worker_ctx(n_shards: int) -> Optional[ParallelCtx]:
@@ -238,18 +254,20 @@ def host_worker_mesh(n_hosts: int, n_shards: int) -> Optional[Mesh]:
 
     Row ``h`` holds host ``h``'s worker shards, so the two-level
     combine is one ``psum`` per mesh level: worker-level within a row,
-    host-level across rows.  Returns None when the platform exposes too
-    few devices — callers fall back to the nested-vmap emulation of the
-    same dataflow, which is bitwise identical on exactly-representable
+    host-level across rows.  Returns None when a CPU platform exposes
+    too few devices — callers fall back to the nested-vmap emulation of
+    the same dataflow, which is bitwise identical on exactly-representable
     sums; the CI multi-device lane runs the real mesh (8 emulated
-    devices cover up to ``(hosts=4, shards=2)``).
+    devices cover up to ``(hosts=4, shards=2)``).  On an accelerator too
+    few devices raises.
     """
     n = n_hosts * n_shards
     if n <= 1:
         return None
     devices = jax.devices()
     if len(devices) < n:
-        return None
+        return _too_few_devices(devices, n,
+                                f"hosts={n_hosts}, shards={n_shards}")
     return Mesh(np.asarray(devices[:n]).reshape(n_hosts, n_shards),
                 (HOST_AXIS, WORKER_AXIS))
 

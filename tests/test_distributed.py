@@ -91,7 +91,8 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.runtime.sharding import ParallelCtx
     from repro.core.distributed import make_fl_aggregate_step
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     ctx = ParallelCtx(mesh=mesh)
     rng = np.random.default_rng(0)
     stacked = {"w": jnp.asarray(rng.normal(size=(2, 8, 704)).astype(np.float32))}

@@ -252,6 +252,44 @@ def test_worker_mesh_requires_devices():
         assert ctx.axis_size(WORKER_AXIS) == n
 
 
+@pytest.mark.parametrize("mode", ["exact", "approx"])
+def test_cpu_emulates_shards_beyond_device_count(mode):
+    """On the CPU a shard count above the device count still runs, as
+    the single-device emulation, bitwise equal to the unsharded round."""
+    shards = jax.device_count() + 1
+    assert worker_mesh(shards) is None
+    rng, flats, prev, pk = _round_inputs(11)
+    events, _ = make_uplink_stream(rng, pk, loss_rate=0.1, dup_rate=0.2)
+    kw = dict(n_clients=6, n_params=480, payload=48, ring_capacity=7,
+              n_workers=shards, mode=mode, compile=True)
+    base = run_engine_round(EngineConfig(**kw), flats, prev, events)
+    got = run_engine_round(EngineConfig(shards=shards, **kw), flats, prev,
+                           events)
+    _assert_rounds_equal(base, got)
+
+
+class _FakeChip:
+    platform = "tpu"
+
+
+def test_accelerator_refuses_emulated_shards(monkeypatch):
+    """On an accelerator too few devices is an error that names the
+    request, never a silent single-device emulation."""
+    from repro.runtime import sharding
+    monkeypatch.setattr(sharding.jax, "devices", lambda: [_FakeChip()])
+    sharding.worker_mesh.cache_clear()
+    sharding.host_worker_mesh.cache_clear()
+    try:
+        with pytest.raises(ValueError, match="shards=4"):
+            sharding.worker_mesh(4)
+        with pytest.raises(ValueError, match="hosts=2, shards=2"):
+            sharding.host_worker_mesh(2, 2)
+        assert sharding.worker_mesh(1) is None      # unsharded: no mesh
+    finally:
+        sharding.worker_mesh.cache_clear()
+        sharding.host_worker_mesh.cache_clear()
+
+
 def test_shards_require_compiled_engine():
     with pytest.raises(ValueError):
         EngineConfig(n_clients=2, n_params=64, payload=16, shards=2)

@@ -155,6 +155,29 @@ def test_docs_rule_line_numbers(tmp_path):
     assert docs.check(tmp_path) == [f"{doc}: broken link -> nope.md"]
 
 
+def test_gitignored_dirs_are_not_scanned(tmp_path):
+    """A directory .gitignore lists (a cache, a local checkout of the
+    repo) holds nothing git commits, so no rule reads it."""
+    doc = "NOTES" + ".md"
+    for d in ("kept", ".local"):
+        (tmp_path / d).mkdir()
+        (tmp_path / d / doc).write_text("[gone](nope.md)\n")
+        (tmp_path / d / "bad.py").write_text("def f(:\n")
+    (tmp_path / ".gitignore").write_text("# local\n.local/\n*.pyc\n")
+    assert core.skip_dirs(tmp_path) == core.SKIP_DIRS | {".local"}
+    assert [f.path for f in docs.check_root(tmp_path)] == [f"kept/{doc}"]
+    assert [f.rel for f in core.Project(tmp_path).files] == ["kept/bad.py"]
+
+
+def test_docs_cite_with_path_resolves_from_root(tmp_path):
+    doc = "NOTES" + ".md"
+    (tmp_path / "sub").mkdir()
+    (tmp_path / "sub" / doc).write_text("# notes\n")
+    (tmp_path / "a.py").write_text(f"# see sub/{doc}\n# see {doc}\n")
+    found = docs.check_root(tmp_path)
+    assert [(f.path, f.line) for f in found] == [("a.py", 2)]
+
+
 # -- shared plumbing -------------------------------------------------------
 
 def test_rule_bits_are_distinct_powers_of_two():

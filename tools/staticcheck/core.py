@@ -44,7 +44,17 @@ RULE_BITS = {
 
 # directories never scanned when a CLI path is expanded (explicitly
 # listed files are always scanned — the fixture corpus relies on that)
-SKIP_DIRS = {".git", "__pycache__", ".pytest_cache", "scratch", "fixtures"}
+SKIP_DIRS = {".git", "__pycache__", ".pytest_cache", "fixtures"}
+
+
+def skip_dirs(root: pathlib.Path) -> Set[str]:
+    """``SKIP_DIRS`` plus every directory the root ``.gitignore`` lists
+    as ``name/``: what git never commits (caches, local checkouts of
+    the repo) is not the repo's code or docs."""
+    ignore = pathlib.Path(root) / ".gitignore"
+    lines = ignore.read_text().splitlines() if ignore.is_file() else []
+    return SKIP_DIRS | {ln.strip()[:-1] for ln in lines
+                        if re.fullmatch(r"[\w.-]+/", ln.strip())}
 
 WAIVER_RE = re.compile(
     r"#\s*staticcheck:\s*allow\(\s*([\w\s,-]+?)\s*\)\s*"
@@ -115,7 +125,7 @@ class Project:
     """The file set one runner invocation analyzes.
 
     ``paths`` are repo-root-relative files or directories; directories
-    expand to every ``*.py`` under them minus ``SKIP_DIRS``.  Explicit
+    expand to every ``*.py`` under them minus ``skip_dirs``.  Explicit
     file paths are never filtered, so the fixture corpus under
     ``tests/fixtures/`` can be analyzed one file at a time.
     """
@@ -123,6 +133,7 @@ class Project:
     def __init__(self, root, paths: Optional[Sequence[str]] = None):
         self.root = pathlib.Path(root).resolve()
         targets = [self.root / p for p in paths] if paths else [self.root]
+        skip = skip_dirs(self.root)
         ordered: List[pathlib.Path] = []
         seen: Set[pathlib.Path] = set()
         for t in targets:
@@ -131,7 +142,7 @@ class Project:
                 rel = p.relative_to(self.root)
                 if (p.is_file() is False
                         or (not t.is_file()
-                            and SKIP_DIRS.intersection(rel.parts))
+                            and skip.intersection(rel.parts))
                         or p in seen):
                     continue
                 seen.add(p)

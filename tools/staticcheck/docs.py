@@ -15,7 +15,8 @@ did not exist):
    resolve to an existing file or directory (anchors stripped;
    http/https/mailto ignored).
 2. **Doc-section citations**: any ``SOMEDOC.md`` occurrence in source
-   or docs must name a repo-root file, and ``SOMEDOC.md §Section`` must
+   or docs must name a repo-root file (or, written with its path, a
+   file at that path from the root), and ``SOMEDOC.md §Section`` must
    match one of its ``## §...`` headings.
 3. **EngineConfig coverage**: every field of the ``EngineConfig``
    dataclass (parsed from ``src/repro/core/server.py`` with ``ast``)
@@ -39,7 +40,10 @@ from tools.staticcheck import core
 RULE = "docs"
 
 MD_LINK = re.compile(r"\[[^\]]*\]\(([^)\s]+)\)")
-DOC_CITE = re.compile(r"\b([A-Z][A-Z0-9_]*\.md)(?:\s+§([A-Za-z0-9][\w-]*))?")
+# a cite is a bare root doc (``DESIGN.md``) or a path to one
+# (``.claude/skills/verify/SKILL.md``), resolved from the repo root
+DOC_CITE = re.compile(r"(?<![\w./-])((?:[\w.-]+/)*[A-Z][A-Z0-9_]*\.md)"
+                      r"(?:\s+§([A-Za-z0-9][\w-]*))?")
 HEADING = re.compile(r"^#{1,6}\s", re.M)
 
 # docstrings here quote dangling references as examples — not cites
@@ -47,8 +51,9 @@ _EXCLUDE_CITES = {"tools/check_doc_links.py", "tools/staticcheck/docs.py"}
 
 
 def _files(root: pathlib.Path, suffix: str):
+    skip = core.skip_dirs(root)
     for p in sorted(root.rglob(f"*{suffix}")):
-        if not core.SKIP_DIRS.intersection(p.relative_to(root).parts):
+        if not skip.intersection(p.relative_to(root).parts):
             yield p
 
 

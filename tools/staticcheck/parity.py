@@ -46,8 +46,9 @@ def _test_identifiers(root) -> Set[str]:
     tests = root / "tests"
     if not tests.is_dir():
         return names
+    skip = core.skip_dirs(root)
     for path in sorted(tests.rglob("*.py")):
-        if core.SKIP_DIRS.intersection(path.relative_to(root).parts):
+        if skip.intersection(path.relative_to(root).parts):
             continue
         try:
             tree = ast.parse(path.read_text())
