@@ -97,6 +97,20 @@ from repro.kernels.packet_scatter import (BLOCK_PKTS, norm_clip_weights,
                                           staleness_weights)
 from repro.runtime.sharding import host_ctx, worker_ctx
 
+# Profiler spans of the served round, as ``jax.profiler.TraceAnnotation``
+# (inert when no trace runs).  Each opens once or twice a round, never
+# per event: ``fl.round`` holds ``run_compiled_round``; ``fl.demux``
+# (host RX) is tiled by its per-event scan, numpy gate, payload stacks
+# and drain schedule; ``fl.h2d`` holds the host-to-device copies as the
+# caller hands them over (PjRt lays them out on its own threads, mostly
+# after the span closes) and ``fl.launch`` the accumulators' allocation
+# and the jitted call.  ``run_compiled_rounds`` shares ``demux_events``
+# and ``dispatch_round``: it opens every span but ``fl.round``, and
+# allocates its accumulators outside ``fl.launch``.
+SPANS = ("fl.round", "fl.demux", "fl.demux.scan", "fl.demux.gate",
+         "fl.demux.stack", "fl.demux.schedule", "fl.h2d", "fl.launch")
+_span = jax.profiler.TraceAnnotation
+
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -403,6 +417,7 @@ def approx_lost_updates(sched: DrainSchedule, n_shards: int = 1
     return lost
 
 
+@functools.partial(jax.profiler.annotate_function, name="fl.demux")
 def demux_events(cfg: EngineConfig, events: Iterable,
                  weights: Optional[np.ndarray] = None
                  ) -> Tuple[DrainSchedule, EngineStats, np.ndarray]:
@@ -425,150 +440,158 @@ def demux_events(cfg: EngineConfig, events: Iterable,
     the stragglers' undelivered packets as wire losses), late ENDs from
     timed-out clients are still grace-ack-counted, and the
     ``min_clients`` quorum guard raises before any device work.
+
+    The call runs in the ``fl.demux`` span, tiled by its phases:
+    ``fl.demux.scan`` (the per-event pass), ``fl.demux.gate`` (the numpy
+    FSM gate, deadline cut, malformed check and dedup),
+    ``fl.demux.stack`` (the payload stacks) and ``fl.demux.schedule``.
     """
     K, n_slots = cfg.n_clients, cfg.n_slots
     wts = (np.ones(K, np.float32) if weights is None
            else np.asarray(weights, np.float32))
-    d_c: List[int] = []
-    d_s: List[int] = []
-    d_pay: List = []
-    d_pos: List[int] = []
-    d_q8: List[bool] = []
-    d_sc: List[float] = []
-    s_c: List[int] = []
-    s_pos: List[int] = []
-    e_c: List[int] = []
-    e_pos: List[int] = []
-    # local bindings keep the one unavoidable per-event pass cheap —
-    # this loop and the payload stack are the whole host RX cost
-    data_k, start_k, end_k = Kind.DATA, Kind.START, Kind.END
-    dc_ap, ds_ap = d_c.append, d_s.append
-    dpay_ap, dpos_ap = d_pay.append, d_pos.append
-    dq_ap, dsc_ap = d_q8.append, d_sc.append
-    pos = 0
-    for packet, payload in events:
-        kind = packet.kind
-        if kind is data_k:
-            dc_ap(packet.client)
-            ds_ap(packet.index)
-            dpay_ap(payload)
-            dpos_ap(pos)
-            dq_ap(packet.wire_dtype != "f32")
-            dsc_ap(packet.scale)
-        elif kind is start_k:
-            s_c.append(packet.client)
-            s_pos.append(pos)
-        elif kind is end_k:
-            e_c.append(packet.client)
-            e_pos.append(pos)
-        pos += 1
-    inf = pos + 1
-    # the uplink barrier closes at the deadline: events at pos >= cut are
-    # past the close (cut = inf replays the no-deadline behavior)
-    deadline_set = cfg.round_deadline is not None
-    cut = cfg.round_deadline if deadline_set else inf
-    first_start = np.full(K, inf, np.int64)
-    if s_c:
-        sc, sp = np.asarray(s_c), np.asarray(s_pos, np.int64)
-        pre = sp < cut
-        np.minimum.at(first_start, sc[pre], sp[pre])
-    first_end = np.full(K, inf, np.int64)
-    if e_c:
-        ec, ep = np.asarray(e_c), np.asarray(e_pos, np.int64)
-        after = (ep > first_start[ec]) & (ep < cut)
-        np.minimum.at(first_end, ec[after], ep[after])
-    stats = EngineStats()
-    # clients short of their END at the close are this round's stragglers
-    timed = (first_end >= inf) if deadline_set else np.zeros(K, bool)
-    stats.stragglers_timed_out = int(np.sum(timed))
-    check_quorum(int(np.sum(first_end < inf)), cfg.min_clients,
-                 stats.stragglers_timed_out)
-    if s_c:       # STARTs in any post-START phase are (re-)acked; a
-                  # TIMED_OUT client's round is closed — no ack past cut
-        stats.control_replies += int(np.sum(
-            (sp >= first_start[sc]) & ~(timed[sc] & (sp >= cut))))
-    if e_c:       # ENDs at/after the accepted END are (re-)acked, and a
-                  # timed-out straggler's late END is grace-acked too
-        stats.control_replies += int(np.sum(
-            (ep >= first_end[ec]) | (timed[ec] & (ep >= cut))))
-    up = np.zeros((K, n_slots), np.float32)
-    if not d_c:
-        sched = build_drain_schedule(
-            np.zeros(0, np.int32), np.zeros(0, np.float32),
-            np.zeros((0, cfg.payload), np.float32),
-            n_workers=cfg.n_workers, ring_capacity=cfg.ring_capacity,
-            ring_assign=cfg.ring_assign, clients=np.zeros(0, np.int32))
-        return sched, stats, up
-    dc = np.asarray(d_c, np.int64)
-    ds = np.asarray(d_s, np.int64)
-    dp = np.asarray(d_pos, np.int64)
-    # every DATA packet past the deadline is late (the eager rx drops it
-    # before the FSM gate); pre-deadline DATA outside its client's
-    # START..END frame is phase-dropped as before
-    pre = dp < cut
-    stats.late_dropped = int(np.sum(~pre))
-    # wire hardening (DESIGN.md §11): non-finite f32 payloads and
-    # zero/negative/non-finite q8 scales are dropped between the
-    # deadline gate and the FSM gate, before the dedup set — same
-    # bucket order as the eager rx, so a clean retransmission of a
-    # poisoned slot is still accepted.  Vectorized: one payload stack
-    # per round, not one isfinite call per packet
-    nd = len(d_c)
-    bad = np.zeros(nd, bool)
-    q8_arr = np.asarray(d_q8, bool)
-    sc_arr = np.asarray(d_sc, np.float32)
-    if q8_arr.any():
-        qi = np.nonzero(q8_arr)[0]
-        bad[qi] = ~(np.isfinite(sc_arr[qi]) & (sc_arr[qi] > 0))
-    pos_in_f32 = np.full(nd, -1, np.int64)
-    f32_stack = None
-    fi = np.nonzero(~q8_arr & np.asarray(
-        [p is not None for p in d_pay], bool))[0]
+    with _span("fl.demux.scan"):
+        d_c: List[int] = []
+        d_s: List[int] = []
+        d_pay: List = []
+        d_pos: List[int] = []
+        d_q8: List[bool] = []
+        d_sc: List[float] = []
+        s_c: List[int] = []
+        s_pos: List[int] = []
+        e_c: List[int] = []
+        e_pos: List[int] = []
+        # local bindings keep the one unavoidable per-event pass cheap
+        data_k, start_k, end_k = Kind.DATA, Kind.START, Kind.END
+        dc_ap, ds_ap = d_c.append, d_s.append
+        dpay_ap, dpos_ap = d_pay.append, d_pos.append
+        dq_ap, dsc_ap = d_q8.append, d_sc.append
+        pos = 0
+        for packet, payload in events:
+            kind = packet.kind
+            if kind is data_k:
+                dc_ap(packet.client)
+                ds_ap(packet.index)
+                dpay_ap(payload)
+                dpos_ap(pos)
+                dq_ap(packet.wire_dtype != "f32")
+                dsc_ap(packet.scale)
+            elif kind is start_k:
+                s_c.append(packet.client)
+                s_pos.append(pos)
+            elif kind is end_k:
+                e_c.append(packet.client)
+                e_pos.append(pos)
+            pos += 1
+    with _span("fl.demux.gate"):
+        inf = pos + 1
+        # the uplink barrier closes at the deadline: events at pos >= cut
+        # are past the close (cut = inf replays the no-deadline behavior)
+        deadline_set = cfg.round_deadline is not None
+        cut = cfg.round_deadline if deadline_set else inf
+        first_start = np.full(K, inf, np.int64)
+        if s_c:
+            sc, sp = np.asarray(s_c), np.asarray(s_pos, np.int64)
+            pre = sp < cut
+            np.minimum.at(first_start, sc[pre], sp[pre])
+        first_end = np.full(K, inf, np.int64)
+        if e_c:
+            ec, ep = np.asarray(e_c), np.asarray(e_pos, np.int64)
+            after = (ep > first_start[ec]) & (ep < cut)
+            np.minimum.at(first_end, ec[after], ep[after])
+        stats = EngineStats()
+        # clients short of their END at the close are this round's
+        # stragglers
+        timed = (first_end >= inf) if deadline_set else np.zeros(K, bool)
+        stats.stragglers_timed_out = int(np.sum(timed))
+        check_quorum(int(np.sum(first_end < inf)), cfg.min_clients,
+                     stats.stragglers_timed_out)
+        if s_c:       # STARTs in any post-START phase are (re-)acked; a
+                      # TIMED_OUT client's round is closed — no ack past cut
+            stats.control_replies += int(np.sum(
+                (sp >= first_start[sc]) & ~(timed[sc] & (sp >= cut))))
+        if e_c:       # ENDs at/after the accepted END are (re-)acked, and a
+                      # timed-out straggler's late END is grace-acked too
+            stats.control_replies += int(np.sum(
+                (ep >= first_end[ec]) | (timed[ec] & (ep >= cut))))
+        up = np.zeros((K, n_slots), np.float32)
+        # a round with no DATA goes the same way, to an empty schedule
+        dc = np.asarray(d_c, np.int64)
+        ds = np.asarray(d_s, np.int64)
+        dp = np.asarray(d_pos, np.int64)
+        # every DATA packet past the deadline is late (the eager rx drops
+        # it before the FSM gate); pre-deadline DATA outside its client's
+        # START..END frame is phase-dropped as before
+        pre = dp < cut
+        stats.late_dropped = int(np.sum(~pre))
+        # wire hardening (DESIGN.md §11): non-finite f32 payloads and
+        # zero/negative/non-finite q8 scales are dropped between the
+        # deadline gate and the FSM gate, before the dedup set — same
+        # bucket order as the eager rx, so a clean retransmission of a
+        # poisoned slot is still accepted.  Vectorized: one payload
+        # stack per round, not one isfinite call per packet
+        nd = len(d_c)
+        bad = np.zeros(nd, bool)
+        q8_arr = np.asarray(d_q8, bool)
+        sc_arr = np.asarray(d_sc, np.float32)
+        if q8_arr.any():
+            qi = np.nonzero(q8_arr)[0]
+            bad[qi] = ~(np.isfinite(sc_arr[qi]) & (sc_arr[qi] > 0))
+        pos_in_f32 = np.full(nd, -1, np.int64)
+        f32_stack = None
+        fi = np.nonzero(~q8_arr & np.asarray(
+            [p is not None for p in d_pay], bool))[0]
     if len(fi):
-        f32_stack = np.asarray([d_pay[i] for i in fi], np.float32)
-        bad[fi] = ~np.isfinite(f32_stack).all(axis=1)
-        pos_in_f32[fi] = np.arange(len(fi))
-    stats.malformed_dropped = int(np.sum(pre & bad))
-    gate = pre & ~bad
-    frame_ok = (dp > first_start[dc]) & (dp < first_end[dc])
-    phase_ok = gate & frame_ok
-    stats.phase_dropped = int(np.sum(gate & ~frame_ok))
-    ok_rows = np.nonzero(phase_ok)[0]
-    keys = dc[ok_rows] * n_slots + ds[ok_rows]
-    _, first_idx = np.unique(keys, return_index=True)
-    acc_rows = ok_rows[np.sort(first_idx)]        # arrival order preserved
-    stats.duplicates_dropped = int(len(ok_rows) - len(first_idx))
-    stats.data_enqueued = int(len(acc_rows))
-    up[dc[acc_rows], ds[acc_rows]] = 1.0
-    # stack only the *accepted* payload rows: dropped DATA may legally
-    # carry no payload (the eager rx phase-drops before its assert)
-    n_q8 = sum(d_q8[i] for i in acc_rows)
-    scales_col = None
-    if n_q8 == 0:
-        # the malformed pass already stacked every candidate f32 row —
-        # reuse that stack instead of a second copy
-        pay = (f32_stack[pos_in_f32[acc_rows]]
-               if len(acc_rows) else np.zeros((0, cfg.payload), np.float32))
-    elif n_q8 == len(acc_rows):
-        # homogeneous q8 round: the schedule stays int8 end to end and
-        # the per-packet scale column rides beside the weights — the
-        # only f32 form of the uplink is built inside the scan body
-        pay = np.asarray([d_pay[i] for i in acc_rows], np.int8)
-        scales_col = np.asarray([d_sc[i] for i in acc_rows], np.float32)
-    else:
-        # mixed f32/q8 round: correctness fallback — decode the q8 rows
-        # host-side into one f32 schedule (same elementwise q * scale
-        # the fused kernel applies, so numerics are unchanged)
-        pay = np.stack([
-            np.asarray(d_pay[i], np.int8).astype(np.float32)
-            * np.float32(d_sc[i]) if d_q8[i]
-            else np.asarray(d_pay[i], np.float32)
-            for i in acc_rows])
-    sched = build_drain_schedule(
-        ds[acc_rows].astype(np.int32), wts[dc[acc_rows]],
-        pay, n_workers=cfg.n_workers,
-        ring_capacity=cfg.ring_capacity, ring_assign=cfg.ring_assign,
-        scales=scales_col, clients=dc[acc_rows].astype(np.int32))
+        with _span("fl.demux.stack"):
+            f32_stack = np.asarray([d_pay[i] for i in fi], np.float32)
+    with _span("fl.demux.gate"):
+        if len(fi):
+            bad[fi] = ~np.isfinite(f32_stack).all(axis=1)
+            pos_in_f32[fi] = np.arange(len(fi))
+        stats.malformed_dropped = int(np.sum(pre & bad))
+        gate = pre & ~bad
+        frame_ok = (dp > first_start[dc]) & (dp < first_end[dc])
+        phase_ok = gate & frame_ok
+        stats.phase_dropped = int(np.sum(gate & ~frame_ok))
+        ok_rows = np.nonzero(phase_ok)[0]
+        keys = dc[ok_rows] * n_slots + ds[ok_rows]
+        _, first_idx = np.unique(keys, return_index=True)
+        acc_rows = ok_rows[np.sort(first_idx)]    # arrival order preserved
+        stats.duplicates_dropped = int(len(ok_rows) - len(first_idx))
+        stats.data_enqueued = int(len(acc_rows))
+        up[dc[acc_rows], ds[acc_rows]] = 1.0
+    with _span("fl.demux.stack"):
+        # stack only the *accepted* payload rows: dropped DATA may legally
+        # carry no payload (the eager rx phase-drops before its assert)
+        n_q8 = sum(d_q8[i] for i in acc_rows)
+        scales_col = None
+        if n_q8 == 0:
+            # the malformed pass already stacked every candidate f32 row —
+            # reuse that stack instead of a second copy
+            pay = (f32_stack[pos_in_f32[acc_rows]] if len(acc_rows)
+                   else np.zeros((0, cfg.payload), np.float32))
+        elif n_q8 == len(acc_rows):
+            # homogeneous q8 round: the schedule stays int8 end to end and
+            # the per-packet scale column rides beside the weights — the
+            # only f32 form of the uplink is built inside the scan body
+            pay = np.asarray([d_pay[i] for i in acc_rows], np.int8)
+            scales_col = np.asarray([d_sc[i] for i in acc_rows], np.float32)
+        else:
+            # mixed f32/q8 round: correctness fallback — decode the q8 rows
+            # host-side into one f32 schedule (same elementwise q * scale
+            # the fused kernel applies, so numerics are unchanged)
+            pay = np.stack([
+                np.asarray(d_pay[i], np.int8).astype(np.float32)
+                * np.float32(d_sc[i]) if d_q8[i]
+                else np.asarray(d_pay[i], np.float32)
+                for i in acc_rows])
+        # freeing the candidate stack is part of the stacking cost
+        del f32_stack
+    with _span("fl.demux.schedule"):
+        sched = build_drain_schedule(
+            ds[acc_rows].astype(np.int32), wts[dc[acc_rows]],
+            pay, n_workers=cfg.n_workers,
+            ring_capacity=cfg.ring_capacity, ring_assign=cfg.ring_assign,
+            scales=scales_col, clients=dc[acc_rows].astype(np.int32))
     stats.batches_drained = sched.n_batches
     return sched, stats, up
 
@@ -796,7 +819,8 @@ def dispatch_round(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     """
     fn, args, kwargs = _round_call(cfg, sched, total, counts, prev_global,
                                    client_flats, down_mask, mix_alpha)
-    return fn(*args, **kwargs)
+    with _span("fl.launch"):
+        return fn(*args, **kwargs)
 
 
 def lower_round(cfg: EngineConfig, sched: DrainSchedule, total, counts,
@@ -844,25 +868,29 @@ def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     def opt(a):
         return None if a is None else jnp.asarray(a)
 
+    with _span("fl.h2d"):
+        if robust_table:
+            args = (jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk),
+                    opt(sc), jnp.asarray(prev_global), opt(client_flats),
+                    opt(down_mask))
+        else:
+            args = (jnp.asarray(total, jnp.float32),
+                    jnp.asarray(counts, jnp.float32), jnp.asarray(idx),
+                    jnp.asarray(w), jnp.asarray(pk), opt(sc),
+                    jnp.asarray(prev_global), opt(client_flats),
+                    opt(down_mask))
     common = dict(payload=cfg.payload, n_params=cfg.n_params,
                   use_pallas=_use_pallas(cfg), block_slots=8,
                   block_pkts=min(BLOCK_PKTS, idx.shape[-1]),
                   mix_alpha=float(mix_alpha), interpret=_interpret(),
                   shards=cfg.shards, hosts=cfg.hosts, mesh=mesh)
     if robust_table:
-        return _robust_round_device, (
-            jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk), opt(sc),
-            jnp.asarray(prev_global), opt(client_flats), opt(down_mask),
-        ), dict(common, n_slots=cfg.n_slots, n_clients=cfg.n_clients,
-                median=(cfg.agg_mode == "median"),
-                beta=float(cfg.trim_beta))
-    return _round_device, (
-        jnp.asarray(total, jnp.float32), jnp.asarray(counts, jnp.float32),
-        jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk), opt(sc),
-        jnp.asarray(prev_global), opt(client_flats), opt(down_mask),
-    ), dict(common, mode=cfg.mode,
-            agg_clip=(cfg.agg_mode == "norm_clip"),
-            clip_tau=float(cfg.clip_tau))
+        return _robust_round_device, args, dict(
+            common, n_slots=cfg.n_slots, n_clients=cfg.n_clients,
+            median=(cfg.agg_mode == "median"), beta=float(cfg.trim_beta))
+    return _round_device, args, dict(
+        common, mode=cfg.mode, agg_clip=(cfg.agg_mode == "norm_clip"),
+        clip_tau=float(cfg.clip_tau))
 
 
 # ---------------------------------------------------------------------------
@@ -873,16 +901,20 @@ def run_compiled_round(cfg: EngineConfig, client_flats, prev_global,
                        events: Iterable, down_mask=None, weights=None,
                        mix_alpha: float = 0.0) -> RoundResult:
     """Compiled counterpart of ``server.run_engine_round``: bulk demux,
-    then exactly one device dispatch for drains + END + TX."""
-    sched, stats, up = demux_events(cfg, events, weights)
-    total = jnp.zeros((cfg.n_slots, cfg.payload), jnp.float32)
-    counts = jnp.zeros((cfg.n_slots,), jnp.float32)
-    _, counts, new_global, new_flats = dispatch_round(
-        cfg, sched, total, counts, prev_global,
-        client_flats=None if down_mask is None else client_flats,
-        down_mask=down_mask, mix_alpha=mix_alpha)
-    return RoundResult(new_global, counts, jnp.asarray(up), new_flats,
-                       stats)
+    then exactly one device dispatch for drains + END + TX.  The call
+    runs in the ``fl.round`` span (``SPANS``)."""
+    with _span("fl.round"):
+        sched, stats, up = demux_events(cfg, events, weights)
+        with _span("fl.launch"):
+            total = jnp.zeros((cfg.n_slots, cfg.payload), jnp.float32)
+            counts = jnp.zeros((cfg.n_slots,), jnp.float32)
+        _, counts, new_global, new_flats = dispatch_round(
+            cfg, sched, total, counts, prev_global,
+            client_flats=None if down_mask is None else client_flats,
+            down_mask=down_mask, mix_alpha=mix_alpha)
+        with _span("fl.h2d"):
+            up = jnp.asarray(up)
+        return RoundResult(new_global, counts, up, new_flats, stats)
 
 
 def run_compiled_rounds(cfg: EngineConfig, rounds: Iterable,

@@ -32,7 +32,10 @@ parameter buffer (§4.1).  Two kernels cover the two server designs:
       arrival, reproducing the lost-update bias of §3.2/§4.
 
 Both kernels run under ``interpret=True`` on CPU (how CI validates
-them); on TPU they compile through Mosaic.
+them); on TPU they compile through Mosaic.  The accumulate kernels
+carry their name, ``fold_f32`` or ``fold_q8``, in the Pallas call's
+``metadata``: the TPU custom call's ``kernel_metadata`` holds it, so a
+device trace's op text names the fold.
 
 Two scan-level entry points extend the accumulate kernel to whole
 rounds (DESIGN.md §3): ``packet_scatter_accum_batch_jnp`` is the
@@ -233,6 +236,7 @@ def packet_scatter_accum_pallas(packets: jnp.ndarray, idx: jnp.ndarray,
         ],
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        metadata={"kernel": "fold_f32"},
     )(idx2d, w2d, packets, acc.astype(jnp.float32),
       counts.astype(jnp.float32))
 
@@ -286,6 +290,7 @@ def packet_scatter_accum_q8_pallas(packets: jnp.ndarray,
         ],
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        metadata={"kernel": "fold_q8"},
     )(idx2d, w2d, s2d, packets.astype(jnp.int8), acc.astype(jnp.float32),
       counts.astype(jnp.float32))
 
