@@ -8,9 +8,14 @@ that pipeline and compiles the *execution*:
 
 1. **Demux pass** (host, vectorized numpy): the event stream — or the
    arrivals an engine recorded — is turned into a dense *drain
-   schedule*: ``(n_batches, B)`` slot/weight arrays and a
-   ``(n_batches, B, W)`` payload tensor, one row per drained ring
-   batch, padded with inert ``idx = -1`` / ``weight = 0`` entries.  The
+   schedule*: ``(n_batches, B)`` slot/weight arrays, one row per
+   drained ring batch, padded with inert ``idx = -1`` / ``weight = 0``
+   entries, and a ``src`` column naming the payload row each entry
+   folds.  The payload rows themselves ship once, in arrival order
+   (``n_batches * ring_capacity`` rows with a zero tail), and the
+   single-chip round expands them to the ``(n_batches, B, W)`` fold
+   input on the device; the shard, host and async paths, which regroup
+   batches on the host, expand them there (``expand_payloads``).  The
    schedule reproduces the eager engine's batching exactly: round-robin
    or slot demux onto ``n_workers`` rings, a drain whenever a ring
    reaches capacity (in arrival order), and the END flush of partial
@@ -133,18 +138,28 @@ def _use_pallas(cfg: EngineConfig) -> bool:
 
 @dataclasses.dataclass
 class DrainSchedule:
-    """Dense per-round drain schedule (host arrays, ready to dispatch).
+    """Per-round drain schedule (host arrays, ready to dispatch).
 
     One row per drain batch; rows beyond ``n_batches`` (row counts are
     bucketed to a multiple of ``pad_batches``, so lossy round-to-round
     batch-count jitter reuses one jit trace instead of retracing) and
     unused columns are inert: ``idx = -1`` matches no slot, weight 0 is
     inert in sums and counts.
+
+    ``payloads`` holds only the rows a round ships: the accepted
+    arrivals in arrival order, then a zero tail up to ``n_rows *
+    ring_capacity`` rows, a length fixed by the bucketed row count so
+    the shape does not move with the accepted count.  ``src`` says which
+    payload row each schedule cell folds; inert cells point one past the
+    end, where a fill-mode gather reads zeros (``expand_payloads`` on
+    the host, ``_take_rows`` on the device).
     """
     idx: np.ndarray         # (n_rows, B) int32 slot rows
     weights: np.ndarray     # (n_rows, B) f32 per-arrival FedAvg weights
-    payloads: np.ndarray    # (n_rows, B, W) payload rows: f32 wire, or
-                            # int8 when ``scales`` is present (q8 wire)
+    payloads: np.ndarray    # (n_rows * ring_capacity, W) payload rows: f32
+                            # wire, or int8 when ``scales`` is present
+    src: np.ndarray         # (n_rows, B) int32 payload row per cell;
+                            # inert cells hold len(payloads)
     n_batches: int          # real drain batches (rest is padding)
     n_packets: int          # accepted arrivals scheduled
     workers: Optional[np.ndarray] = None   # (n_rows,) owning worker ring
@@ -166,10 +181,24 @@ class DrainSchedule:
                                            # columns this schedule was
                                            # built from: (slots, weights,
                                            # payloads, scales, staleness,
-                                           # clients) — cheap references,
-                                           # kept so the hierarchical
-                                           # path can re-demux per host
+                                           # clients) — cheap references
+                                           # (payloads is a view of the
+                                           # shipped rows), kept so the
+                                           # hierarchical path can
+                                           # re-demux per host
                                            # (DESIGN.md §12)
+
+
+def expand_payloads(sched: DrainSchedule) -> np.ndarray:
+    """The ``(n_rows, B, W)`` payload tensor the fold reads, built on
+    the host: shipped row ``src[r, c]`` at cell ``(r, c)``, zeros at the
+    inert cells.  The paths that regroup batches on the host (shards,
+    hosts, async windows) fold this form."""
+    pk, src = sched.payloads, sched.src
+    out = np.zeros(src.shape + pk.shape[1:], pk.dtype)
+    live = src < pk.shape[0]
+    out[live] = pk[src[live]]
+    return out
 
 
 def build_drain_schedule(slots: np.ndarray, weights: np.ndarray,
@@ -201,23 +230,28 @@ def build_drain_schedule(slots: np.ndarray, weights: np.ndarray,
     fold time (DESIGN.md §10), carried as one more column; padding gets
     staleness 0, inert because its weight is 0 in every weighting mode.
     """
+    sched = _drain_layout(slots, weights, int(payloads.shape[1]),
+                          n_workers=n_workers, ring_capacity=ring_capacity,
+                          ring_assign=ring_assign, block_pkts=block_pkts,
+                          pad_batches=pad_batches, scales=scales,
+                          staleness=staleness, clients=clients)
+    sched.payloads[:sched.n_packets] = payloads
+    return sched
+
+
+def _drain_layout(slots: np.ndarray, weights: np.ndarray, width: int, *,
+                  n_workers: int, ring_capacity: int, ring_assign: str = "rr",
+                  block_pkts: int = BLOCK_PKTS, pad_batches: int = 8,
+                  scales: Optional[np.ndarray] = None,
+                  staleness: Optional[np.ndarray] = None,
+                  clients: Optional[np.ndarray] = None) -> DrainSchedule:
+    """``build_drain_schedule`` less its payload copy: ``payloads``
+    comes back with its zero tail and its first ``n`` rows unset, for
+    the caller to fill with the accepted rows in arrival order, so each
+    row is copied once."""
     n = int(slots.shape[0])
-    W = int(payloads.shape[1])
     B = ring_capacity + (-ring_capacity) % block_pkts
     pk_dtype = np.float32 if scales is None else np.int8
-    arrivals = (slots, weights, payloads, scales, staleness, clients)
-    if n == 0:
-        return DrainSchedule(np.full((1, B), -1, np.int32),
-                             np.zeros((1, B), np.float32),
-                             np.zeros((1, B, W), pk_dtype), 0, 0,
-                             np.full((1,), -1, np.int64),
-                             None if scales is None
-                             else np.zeros((1, B), np.float32),
-                             None if staleness is None
-                             else np.zeros((1, B), np.float32),
-                             None if clients is None
-                             else np.full((1, B), -1, np.int32),
-                             arrivals)
     if ring_assign == "slot":
         worker = slots.astype(np.int64) % n_workers
     else:
@@ -244,13 +278,19 @@ def build_drain_schedule(slots: np.ndarray, weights: np.ndarray,
     rank[order] = np.arange(len(order))
     row = rank[inv]
     nb = len(uniq)
-    n_rows = (nb + (-nb) % pad_batches) if pad_batches > 1 else nb
+    # an empty round still has one (inert) row
+    n_rows = max(1, (nb + (-nb) % pad_batches) if pad_batches > 1 else nb)
     idx = np.full((n_rows, B), -1, np.int32)
     w = np.zeros((n_rows, B), np.float32)
-    pk = np.zeros((n_rows, B, W), pk_dtype)
     idx[row, col] = slots
     w[row, col] = weights
-    pk[row, col] = payloads
+    # a batch holds at most ring_capacity arrivals, so n <= rows_out; the
+    # leading size follows n_rows alone, never the accepted count
+    rows_out = n_rows * ring_capacity
+    src = np.full((n_rows, B), rows_out, np.int32)
+    src[row, col] = np.arange(n, dtype=np.int32)
+    pk = np.empty((rows_out, width), pk_dtype)
+    pk[n:] = 0
     sc = None
     if scales is not None:
         sc = np.zeros((n_rows, B), np.float32)
@@ -265,8 +305,9 @@ def build_drain_schedule(slots: np.ndarray, weights: np.ndarray,
         cl[row, col] = clients
     row_worker = np.full(n_rows, -1, np.int64)
     row_worker[rank] = uniq // (n + 1)            # batch key -> its worker
-    return DrainSchedule(idx, w, pk, int(nb), n, row_worker, sc, st, cl,
-                         arrivals)
+    return DrainSchedule(idx, w, pk, src, int(nb), n, row_worker, sc, st, cl,
+                         (slots, weights, pk[:n], scales, staleness,
+                          clients))
 
 
 def shard_schedule(sched: DrainSchedule, n_shards: int, *,
@@ -285,7 +326,8 @@ def shard_schedule(sched: DrainSchedule, n_shards: int, *,
     additive across batches).
 
     Returns ``(idx, weights, payloads, scales, staleness)`` with a
-    leading ``(n_shards,)`` axis (``scales`` is None on the f32 wire
+    leading ``(n_shards,)`` axis, payloads expanded on the host to
+    ``(n_shards, rows, B, W)`` (``scales`` is None on the f32 wire
     path, ``staleness`` on synchronous rounds); shards are padded to a
     common row count (bucketed to a multiple of ``pad_batches`` so
     round-to-round jitter reuses one jit trace) with inert rows, and
@@ -294,7 +336,8 @@ def shard_schedule(sched: DrainSchedule, n_shards: int, *,
     """
     assert sched.workers is not None, "schedule predates worker tracking"
     B = sched.idx.shape[1]
-    W = sched.payloads.shape[2]
+    payloads = expand_payloads(sched)
+    W = payloads.shape[2]
     live = sched.workers[:sched.n_batches]
     per_shard = [np.nonzero(live % n_shards == s)[0]
                  for s in range(n_shards)]
@@ -304,7 +347,7 @@ def shard_schedule(sched: DrainSchedule, n_shards: int, *,
         rows += (-rows) % pad_batches
     idx = np.full((n_shards, rows, B), -1, np.int32)
     w = np.zeros((n_shards, rows, B), np.float32)
-    pk = np.zeros((n_shards, rows, B, W), sched.payloads.dtype)
+    pk = np.zeros((n_shards, rows, B, W), payloads.dtype)
     sc = (None if sched.scales is None
           else np.zeros((n_shards, rows, B), np.float32))
     st = (None if sched.staleness is None
@@ -312,7 +355,7 @@ def shard_schedule(sched: DrainSchedule, n_shards: int, *,
     for s, p in enumerate(per_shard):
         idx[s, :len(p)] = sched.idx[p]
         w[s, :len(p)] = sched.weights[p]
-        pk[s, :len(p)] = sched.payloads[p]
+        pk[s, :len(p)] = payloads[p]
         if sc is not None:
             sc[s, :len(p)] = sched.scales[p]
         if st is not None:
@@ -559,39 +602,42 @@ def demux_events(cfg: EngineConfig, events: Iterable,
         stats.duplicates_dropped = int(len(ok_rows) - len(first_idx))
         stats.data_enqueued = int(len(acc_rows))
         up[dc[acc_rows], ds[acc_rows]] = 1.0
+        n_q8 = int(q8_arr[acc_rows].sum())
+        # a homogeneous q8 round stays int8 end to end and the per-packet
+        # scale column rides beside the weights — the only f32 form of the
+        # uplink is built inside the scan body
+        q8_round = 0 < n_q8 == len(acc_rows)
+    with _span("fl.demux.schedule"):
+        sched = _drain_layout(
+            ds[acc_rows].astype(np.int32), wts[dc[acc_rows]], cfg.payload,
+            n_workers=cfg.n_workers, ring_capacity=cfg.ring_capacity,
+            ring_assign=cfg.ring_assign,
+            scales=sc_arr[acc_rows] if q8_round else None,
+            clients=dc[acc_rows].astype(np.int32))
     with _span("fl.demux.stack"):
-        # stack only the *accepted* payload rows: dropped DATA may legally
-        # carry no payload (the eager rx phase-drops before its assert)
-        n_q8 = sum(d_q8[i] for i in acc_rows)
-        scales_col = None
-        if n_q8 == 0:
-            # the malformed pass already stacked every candidate f32 row —
-            # reuse that stack instead of a second copy
-            pay = (f32_stack[pos_in_f32[acc_rows]] if len(acc_rows)
-                   else np.zeros((0, cfg.payload), np.float32))
-        elif n_q8 == len(acc_rows):
-            # homogeneous q8 round: the schedule stays int8 end to end and
-            # the per-packet scale column rides beside the weights — the
-            # only f32 form of the uplink is built inside the scan body
-            pay = np.asarray([d_pay[i] for i in acc_rows], np.int8)
-            scales_col = np.asarray([d_sc[i] for i in acc_rows], np.float32)
-        else:
+        # copy only the *accepted* payload rows, once, straight into the
+        # shipped array: dropped DATA may legally carry no payload (the
+        # eager rx phase-drops before its assert)
+        pay = sched.payloads[:len(acc_rows)]
+        if q8_round:
+            pay[...] = [d_pay[i] for i in acc_rows]
+        elif n_q8:
             # mixed f32/q8 round: correctness fallback — decode the q8 rows
             # host-side into one f32 schedule (same elementwise q * scale
             # the fused kernel applies, so numerics are unchanged)
-            pay = np.stack([
+            pay[...] = [
                 np.asarray(d_pay[i], np.int8).astype(np.float32)
                 * np.float32(d_sc[i]) if d_q8[i]
                 else np.asarray(d_pay[i], np.float32)
-                for i in acc_rows])
+                for i in acc_rows]
+        elif len(acc_rows):
+            # the malformed pass already stacked every candidate f32 row —
+            # gather from that stack instead of a second copy ("wrap", as
+            # mode "raise" would buffer the gather before the copy)
+            np.take(f32_stack, pos_in_f32[acc_rows], axis=0, out=pay,
+                    mode="wrap")
         # freeing the candidate stack is part of the stacking cost
         del f32_stack
-    with _span("fl.demux.schedule"):
-        sched = build_drain_schedule(
-            ds[acc_rows].astype(np.int32), wts[dc[acc_rows]],
-            pay, n_workers=cfg.n_workers,
-            ring_capacity=cfg.ring_capacity, ring_assign=cfg.ring_assign,
-            scales=scales_col, clients=dc[acc_rows].astype(np.int32))
     stats.batches_drained = sched.n_batches
     return sched, stats, up
 
@@ -600,6 +646,15 @@ def demux_events(cfg: EngineConfig, events: Iterable,
 # Device: one jitted lax.scan per round, donated accumulators
 # ---------------------------------------------------------------------------
 
+def _take_rows(rows, src):
+    """``expand_payloads`` on the device: the shipped payload rows laid
+    out as the ``(n_rows, B, W)`` fold input.  Inert cells hold
+    ``len(rows)``, past the end, where ``mode="fill"`` reads zeros (an
+    index of -1 would wrap onto the last row), so the tensor is bitwise
+    the host-expanded one."""
+    return jnp.take(rows, src, axis=0, mode="fill", fill_value=0)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("mode", "payload", "n_params",
                                     "use_pallas", "block_slots",
@@ -607,8 +662,9 @@ def demux_events(cfg: EngineConfig, events: Iterable,
                                     "agg_clip", "clip_tau",
                                     "shards", "hosts", "mesh"),
                    donate_argnums=(0, 1))
-def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_scales,
-                  prev_global, client_flats, down_mask, *, mode: str,
+def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_src,
+                  sched_scales, prev_global, client_flats, down_mask, *,
+                  mode: str,
                   payload: int, n_params: int, use_pallas: bool,
                   block_slots: int, block_pkts: int, mix_alpha: float,
                   interpret: bool, agg_clip: bool = False,
@@ -621,6 +677,11 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_scales,
     sequence of ``StreamingAggregator.finalize`` + ``finalize_round``)
     and — when ``client_flats``/``down_mask`` are present — the TX
     downlink fallback run fused in the same call.
+
+    With ``sched_src`` present (the single-chip round) ``sched_pk``
+    holds only the shipped rows, and the ``(n_rows, B, W)`` tensor the
+    fold reads is expanded here, on the device (``_take_rows``); without
+    it ``sched_pk`` arrives expanded on the host.
 
     On the q8 wire path ``sched_pk`` is int8 and ``sched_scales``
     carries the per-packet dequant scales; dequantization happens
@@ -642,6 +703,8 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_scales,
     if pad:
         acc = jnp.pad(acc, ((0, pad), (0, 0)))
         cnt = jnp.pad(cnt, ((0, pad), (0, 0)))
+    if sched_src is not None:
+        sched_pk = _take_rows(sched_pk, sched_src)
     if agg_clip:
         # norm_clip mode (§11): bound each packet's influence before the
         # fold — elementwise per packet, so the schedule's grouping (and
@@ -690,8 +753,9 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_scales,
                                     "block_slots", "block_pkts",
                                     "mix_alpha", "interpret", "median",
                                     "beta", "shards", "hosts", "mesh"))
-def _robust_round_device(sched_idx, sched_w, sched_pk, sched_scales,
-                         prev_global, client_flats, down_mask, *,
+def _robust_round_device(sched_idx, sched_w, sched_pk, sched_src,
+                         sched_scales, prev_global, client_flats, down_mask,
+                         *,
                          payload: int, n_params: int, n_slots: int,
                          n_clients: int, use_pallas: bool,
                          block_slots: int, block_pkts: int,
@@ -712,6 +776,7 @@ def _robust_round_device(sched_idx, sched_w, sched_pk, sched_scales,
     reshaped table feeds the fused rank-select finalize; the per-slot
     contributor count ``m`` replaces the mean path's ``counts`` (same
     fallback semantics), and the TX downlink fuses in as usual.
+    ``sched_src`` is read as in ``_round_device``.
 
     No donation: the carried ``(S, W)`` accumulators are the wrong
     shape for the table; the returned ``total`` is the table's per-slot
@@ -719,6 +784,8 @@ def _robust_round_device(sched_idx, sched_w, sched_pk, sched_scales,
     """
     S, K = n_slots, n_clients
     SK = S * K
+    if sched_src is not None:
+        sched_pk = _take_rows(sched_pk, sched_src)
     # jnp single-shard path: the unique combined indices let the whole
     # schedule fold as ONE flat scatter (packet_table_scatter) instead
     # of the batch scan — the scan's per-batch (S·K, B) one-hot routing
@@ -841,7 +908,7 @@ def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     if cfg.mode not in ("exact", "approx"):
         raise ValueError(cfg.mode)
     robust_table = cfg.agg_mode in ("trimmed_mean", "median")
-    mesh = None
+    mesh = src = None
     if cfg.hosts > 1:
         # partition BEFORE the robust index rewrite (ownership keys on
         # the original client column) and before the shard split (ring
@@ -859,24 +926,27 @@ def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     else:
         if robust_table:
             sched = _combined_table_sched(sched, cfg.n_clients)
-        idx, w, pk, sc = (sched.idx, sched.weights, sched.payloads,
-                          sched.scales)
         if cfg.shards > 1:
+            # shards regroup batches on the host, so they ship payloads
+            # expanded there; one chip ships the compact rows and src
             idx, w, pk, sc, _ = shard_schedule(sched, cfg.shards)
             ctx = worker_ctx(cfg.shards)
             mesh = None if ctx is None else ctx.mesh
+        else:
+            idx, w, pk, src, sc = (sched.idx, sched.weights,
+                                   sched.payloads, sched.src, sched.scales)
     def opt(a):
         return None if a is None else jnp.asarray(a)
 
     with _span("fl.h2d"):
         if robust_table:
             args = (jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk),
-                    opt(sc), jnp.asarray(prev_global), opt(client_flats),
-                    opt(down_mask))
+                    opt(src), opt(sc), jnp.asarray(prev_global),
+                    opt(client_flats), opt(down_mask))
         else:
             args = (jnp.asarray(total, jnp.float32),
                     jnp.asarray(counts, jnp.float32), jnp.asarray(idx),
-                    jnp.asarray(w), jnp.asarray(pk), opt(sc),
+                    jnp.asarray(w), jnp.asarray(pk), opt(src), opt(sc),
                     jnp.asarray(prev_global), opt(client_flats),
                     opt(down_mask))
     common = dict(payload=cfg.payload, n_params=cfg.n_params,
@@ -1134,7 +1204,7 @@ def demux_events_async(cfg: EngineConfig, events: Iterable,
             np.zeros((0,), bool), 0, 0, pending, None, [])
         return asched, stats, updates
     B = scheds[0].idx.shape[1]
-    W = scheds[0].payloads.shape[2]
+    W = scheds[0].payloads.shape[1]
     R = max(s.idx.shape[0] for s in scheds)
     idx = np.full((n_windows, R, B), -1, np.int32)
     w_all = np.zeros((n_windows, R, B), np.float32)
@@ -1147,7 +1217,7 @@ def demux_events_async(cfg: EngineConfig, events: Iterable,
         idx[i, :r] = s.idx
         w_all[i, :r] = s.weights
         st_all[i, :r] = s.staleness
-        pk_all[i, :r] = s.payloads
+        pk_all[i, :r] = expand_payloads(s)
         if sc_all is not None:
             sc_all[i, :r] = s.scales
     asched = AsyncSchedule(idx, w_all, st_all, pk_all,

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _drain_reference import (accepted_rows, assert_expands_to,
+                              dense_payloads)
 from _hypothesis_shim import given, settings, st
 
 from repro.core import engine_compiled as ec
@@ -249,11 +251,88 @@ def test_round_dispatch_donates_accumulators():
         total := jnp.zeros((cfg.n_slots, 32), jnp.float32),
         jnp.zeros((cfg.n_slots,), jnp.float32),
         jnp.asarray(sched.idx), jnp.asarray(sched.weights),
-        jnp.asarray(sched.payloads), None, prev, None, None,
+        jnp.asarray(ec.expand_payloads(sched)), None, None, prev, None,
+        None,
         mode="exact", payload=32, n_params=128, use_pallas=False,
         block_slots=8, block_pkts=128, mix_alpha=0.0, interpret=True,
         shards=1, mesh=None)
     assert "tf.aliasing_output" in lowered.as_text()
+
+
+@pytest.mark.parametrize("assign", ["rr", "slot"])
+def test_shipped_rows_expand_to_the_dense_schedule(assign):
+    """A round ships only its accepted payload rows; expanded on the
+    host (``expand_payloads``) or on the device (the round's own
+    gather) they are bitwise the dense (n_rows, B, W) tensor of the
+    eager batching, on a lossy, duplicated, reordered stream."""
+    rng, _, _, pk = _round_inputs(3, int_valued=False)
+    events, _ = make_uplink_stream(rng, pk, loss_rate=0.2, dup_rate=0.3)
+    cfg = EngineConfig(n_clients=10, n_params=1000, payload=64,
+                       n_workers=3, ring_capacity=7, ring_assign=assign,
+                       compile=True)
+    sched, _, _ = ec.demux_events(cfg, events)
+    slots, rows = accepted_rows(events)
+    assert rows.dtype == np.float32 and sched.n_packets == len(slots)
+    assert_expands_to(sched, dense_payloads(
+        slots, rows, n_workers=3, ring_capacity=7, ring_assign=assign), 7)
+
+
+def test_empty_round_ships_only_a_zero_tail():
+    cfg = EngineConfig(n_clients=2, n_params=64, payload=16, n_workers=3,
+                       ring_capacity=4, compile=True)
+    events = [(Packet(Kind.START, c), None) for c in range(2)] + \
+        [(Packet(Kind.END, c), None) for c in range(2)]
+    sched, _, _ = ec.demux_events(cfg, events)
+    built = ec.build_drain_schedule(
+        np.zeros(0, np.int32), np.zeros(0, np.float32),
+        np.zeros((0, 16), np.float32), n_workers=3, ring_capacity=4)
+    for s in (sched, built):
+        assert s.n_packets == 0 and not s.payloads.any()
+        assert_expands_to(s, np.zeros((1, 128, 16), np.float32), 4)
+
+
+def test_full_batches_leave_no_spare_row_and_pad_past_the_end():
+    """Every batch full and the row count already a multiple of 8: the
+    shipped rows are exactly the arrivals, no zero row exists, and the
+    inert cells must point past the end (-1 would wrap onto the last
+    arrival's row)."""
+    rng = np.random.default_rng(5)
+    slots = rng.permutation(64).astype(np.int32)
+    rows = rng.normal(size=(64, 24)).astype(np.float32) + 1.0
+    sched = ec.build_drain_schedule(slots, np.ones(64, np.float32), rows,
+                                    n_workers=2, ring_capacity=4)
+    assert sched.n_batches == sched.idx.shape[0] == 16
+    assert sched.payloads.shape[0] == 64 == sched.n_packets
+    assert (sched.src[sched.idx < 0] == 64).all()
+    assert_expands_to(sched, dense_payloads(slots, rows, n_workers=2,
+                                            ring_capacity=4), 4)
+
+
+def test_shipped_shape_follows_rows_not_accepted_count():
+    """Two rounds that accept different numbers of packets into the
+    same bucketed row count ship arrays of one shape, so the second
+    round compiles nothing."""
+    cfg = EngineConfig(n_clients=10, n_params=1000, payload=64,
+                       n_workers=3, ring_capacity=7, compile=True)
+    scheds = []
+    for seed in (0, 1):
+        rng, _, prev, pk = _round_inputs(seed, int_valued=False)
+        events, _ = make_uplink_stream(rng, pk, loss_rate=0.2,
+                                       dup_rate=0.3)
+        scheds.append(ec.demux_events(cfg, events)[0])
+    a, b = scheds
+    assert a.n_packets != b.n_packets
+    assert a.idx.shape == b.idx.shape
+    assert a.payloads.shape == b.payloads.shape == (a.idx.shape[0] * 7, 64)
+
+    def dispatch(sched):
+        return ec.dispatch_round(
+            cfg, sched, jnp.zeros((cfg.n_slots, 64), jnp.float32),
+            jnp.zeros((cfg.n_slots,), jnp.float32), prev)[2]
+    dispatch(a).block_until_ready()
+    before = ec._round_device._cache_size()
+    dispatch(b).block_until_ready()
+    assert ec._round_device._cache_size() == before
 
 
 def test_ops_scatter_accum_donation_is_opt_in():

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _drain_reference import (accepted_rows, assert_expands_to,
+                              dense_payloads)
 
 from repro.core import packets as pktmod
 from repro.core.aggregation import quantize_packets
@@ -276,6 +278,32 @@ def test_q8_schedule_stays_int8_end_to_end():
     assert sched_f.payloads.dtype == np.float32
     assert sched_f.scales is None
     assert ec.shard_schedule(sched_f, 4)[3] is None
+
+
+@pytest.mark.parametrize("wire", ["q8", "mixed"])
+def test_shipped_wire_rows_expand_to_the_dense_schedule(wire):
+    """The q8 round ships its accepted int8 rows, the mixed-wire round
+    its host-decoded f32 rows; either way both expansions are bitwise
+    the dense tensor of the eager batching, scales riding on the
+    (n_rows, B) grid beside the weights."""
+    flats = _flats(6)
+    q, sc = _q8_of(flats)
+    deq = _dequant_host(q, sc)
+    cfg = EngineConfig(n_clients=K, n_params=P, payload=W, n_workers=3,
+                       ring_capacity=5, compile=True)
+    ev, _ = make_uplink_stream(np.random.default_rng(19), q, loss_rate=0.1,
+                               dup_rate=0.2, scales=sc)
+    if wire == "mixed":
+        ev = [(pkt, pay) if pkt.kind is not Kind.DATA or pkt.client % 2
+              else (dataclasses.replace(pkt, wire_dtype="f32", scale=1.0),
+                    deq[pkt.client, pkt.index])
+              for pkt, pay in ev]
+    sched, _, _ = ec.demux_events(cfg, ev)
+    slots, rows = accepted_rows(ev, decode=(wire == "mixed"))
+    assert rows.dtype == (np.int8 if wire == "q8" else np.float32)
+    assert (sched.scales is not None) == (wire == "q8")
+    assert_expands_to(sched, dense_payloads(slots, rows, n_workers=3,
+                                            ring_capacity=5), 5)
 
 
 def test_q8_deadline_and_dedup_semantics_unchanged():

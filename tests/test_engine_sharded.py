@@ -173,7 +173,8 @@ def test_shard_schedule_is_a_partition(shards):
     # payload rows ride with their batch
     total_pk = pk.reshape(-1, pk.shape[-2], pk.shape[-1]).sum(axis=0)
     np.testing.assert_allclose(total_pk.sum(),
-                               sched.payloads[:sched.n_batches].sum(),
+                               ec.expand_payloads(sched)[:sched.n_batches]
+                               .sum(),
                                rtol=1e-6)
 
 

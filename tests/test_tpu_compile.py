@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import engine_compiled as ec
 from repro.kernels.fedavg_accum import fedavg_accum_pallas
 from repro.kernels.packet_scatter import (packet_scatter_accum_pallas,
                                           packet_scatter_accum_q8_pallas,
@@ -82,6 +83,24 @@ def test_pallas_scan_compiles(spec):
         i, w, p, a, c, use_pallas=True),
         spec((rows, b), jnp.int32), spec((rows, b)),
         spec((rows, b, W_F32)), spec((S_F32, W_F32)), spec((S_F32, 1)))
+
+
+@pytest.mark.parametrize("q8", [False, True])
+def test_single_chip_round_compiles(spec, q8):
+    """The whole round ``lower_round`` gives a single-chip schedule on
+    the chip: the shipped rows (a ring of 64 per drained batch) are
+    gathered into the 128-packet blocks on the device and folded by
+    the Pallas kernel."""
+    rows, b, s = 8, 128, 64       # the real widths; few slots, to compile fast
+    w = W_Q8 if q8 else W_F32
+    text = ec._round_device.lower(
+        spec((s, w)), spec((s,)), spec((rows, b), jnp.int32),
+        spec((rows, b)), spec((rows * B, w), jnp.int8 if q8 else jnp.float32),
+        spec((rows, b), jnp.int32), spec((rows, b)) if q8 else None,
+        spec((s * w,)), None, None, mode="exact", payload=w,
+        n_params=s * w, use_pallas=True, block_slots=8, block_pkts=b,
+        mix_alpha=0.0, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text and "gather" in text
 
 
 @pytest.mark.parametrize("k", [64, 256])
