@@ -115,6 +115,9 @@ from repro.runtime.sharding import host_ctx, worker_ctx
 SPANS = ("fl.round", "fl.demux", "fl.demux.scan", "fl.demux.gate",
          "fl.demux.stack", "fl.demux.schedule", "fl.h2d", "fl.launch")
 _span = jax.profiler.TraceAnnotation
+# the robust modes that fold into a per-slot client table (DESIGN.md
+# §11) in place of the (S, W) sum and (S,) counts
+TABLE_MODES = ("trimmed_mean", "median")
 
 
 def _interpret() -> bool:
@@ -907,7 +910,7 @@ def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     """(jitted round, args, static kwargs) for one round's schedule."""
     if cfg.mode not in ("exact", "approx"):
         raise ValueError(cfg.mode)
-    robust_table = cfg.agg_mode in ("trimmed_mean", "median")
+    robust_table = cfg.agg_mode in TABLE_MODES
     mesh = src = None
     if cfg.hosts > 1:
         # partition BEFORE the robust index rewrite (ownership keys on
@@ -967,6 +970,16 @@ def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
 # Drivers: single round, and double-buffered multi-round overlap
 # ---------------------------------------------------------------------------
 
+def _accumulators(cfg: EngineConfig):
+    """The zeroed ``(S, W)`` sum and ``(S,)`` counts a mean-family
+    round folds into and donates; ``(None, None)`` for the table modes,
+    whose round builds its own client table."""
+    if cfg.agg_mode in TABLE_MODES:
+        return None, None
+    return (jnp.zeros((cfg.n_slots, cfg.payload), jnp.float32),
+            jnp.zeros((cfg.n_slots,), jnp.float32))
+
+
 def run_compiled_round(cfg: EngineConfig, client_flats, prev_global,
                        events: Iterable, down_mask=None, weights=None,
                        mix_alpha: float = 0.0) -> RoundResult:
@@ -976,8 +989,7 @@ def run_compiled_round(cfg: EngineConfig, client_flats, prev_global,
     with _span("fl.round"):
         sched, stats, up = demux_events(cfg, events, weights)
         with _span("fl.launch"):
-            total = jnp.zeros((cfg.n_slots, cfg.payload), jnp.float32)
-            counts = jnp.zeros((cfg.n_slots,), jnp.float32)
+            total, counts = _accumulators(cfg)
         _, counts, new_global, new_flats = dispatch_round(
             cfg, sched, total, counts, prev_global,
             client_flats=None if down_mask is None else client_flats,
@@ -1020,8 +1032,7 @@ def run_compiled_rounds(cfg: EngineConfig, rounds: Iterable,
             # staticcheck: allow(hostsync) — overlap-driver barrier: round r-1 is collected only after round r's demux, preserving the double-buffered overlap (DESIGN.md §3)
             pending.new_global.block_until_ready()
             results.append(pending)
-        total = jnp.zeros((cfg.n_slots, cfg.payload), jnp.float32)
-        counts = jnp.zeros((cfg.n_slots,), jnp.float32)
+        total, counts = _accumulators(cfg)
         _, counts, new_global, new_flats = dispatch_round(
             cfg, sched, total, counts, prev,
             client_flats=None if down_mask is None else client_flats,

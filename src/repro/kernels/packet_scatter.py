@@ -34,8 +34,9 @@ parameter buffer (§4.1).  Two kernels cover the two server designs:
 Both kernels run under ``interpret=True`` on CPU (how CI validates
 them); on TPU they compile through Mosaic.  The accumulate kernels
 carry their name, ``fold_f32`` or ``fold_q8``, in the Pallas call's
-``metadata``: the TPU custom call's ``kernel_metadata`` holds it, so a
-device trace's op text names the fold.
+``metadata``, and the robust finalize carries ``robust_finalize``: the
+TPU custom call's ``kernel_metadata`` holds it, so a device trace's op
+text names the kernel.
 
 Two scan-level entry points extend the accumulate kernel to whole
 rounds (DESIGN.md §3): ``packet_scatter_accum_batch_jnp`` is the
@@ -501,6 +502,7 @@ def robust_finalize_pallas(table: jnp.ndarray, pres: jnp.ndarray, *,
         ],
         scratch_shapes=[pltpu.VMEM((block_slots, K, lanes), jnp.float32)],
         interpret=interpret,
+        metadata={"kernel": "robust_finalize"},
     )(table.astype(jnp.float32), pres.astype(jnp.float32))
     return agg, m[:, 0]
 

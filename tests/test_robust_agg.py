@@ -18,6 +18,8 @@ Three layers of contract:
    escapes — under loss/dup/churn streams.
 """
 import dataclasses
+import importlib.util
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from _hypothesis_shim import given, settings, st
 
 from repro.core.aggregation import quantize_packets
 from repro.core.packets import packetize
+from repro.core.protocol import Kind
 from repro.core.rounds import (AttackConfig, ChurnConfig, apply_attack,
                                run_churn_rounds)
 from repro.core.server import (AsyncServerEngine, EngineConfig,
@@ -210,6 +213,49 @@ def test_finalize_pallas_interpret_matches_jnp():
                                         beta=beta, interpret=True)
         np.testing.assert_array_equal(np.asarray(aj), np.asarray(ap))
         np.testing.assert_array_equal(np.asarray(mj), np.asarray(mp))
+
+
+def _bench_reference(name):
+    path = (Path(__file__).resolve().parents[1] / "bench" / "references"
+            / f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"bench_ref_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_pallas_round_matches_the_bench_trimmed_reference():
+    """The served trimmed-mean round with the TPU body (the table fold
+    and the rank-select finalize as Pallas kernels, interpreted here) at
+    K = 10 and the benchmark's beta, against the benchmark's plain
+    float64 reference: slots that all ten delivered trim one value a
+    side, the rest none, and a burst nobody delivered keeps the
+    previous global exactly."""
+    ref = _bench_reference("trimmed_mean")
+    k, n, w = 10, 24, 367
+    rng = np.random.default_rng(15)
+    pk = rng.normal(0.0, 0.05, (k, n, w)).astype(np.float32)
+    events, up = make_uplink_stream(rng, jnp.asarray(pk), loss_rate=0.05,
+                                    dup_rate=0.1)
+    # a burst: slots 5 and 6 reach the server from nobody
+    events = [e for e in events
+              if not (e[0].kind == Kind.DATA and e[0].index in (5, 6))]
+    up = np.asarray(up) > 0
+    up[:, 5:7] = False
+    prev = rng.normal(0.0, 0.05, n * w).astype(np.float32)
+    cfg = EngineConfig(n_clients=k, n_params=n * w, payload=w,
+                       ring_capacity=64, agg_mode="trimmed_mean",
+                       compile=True, scan_body="pallas")
+    assert cfg.trim_beta == ref.BETA
+    res = run_engine_round(cfg, None, jnp.asarray(prev), events)
+    want = ref.round_reference(pk, None, up)
+    counts = np.asarray(res.counts)
+    np.testing.assert_array_equal(counts, want.counts)
+    assert {0, 9, 10} <= set(counts.tolist())
+    value, scale = next(ref.chain([want], prev, [0], n * w))
+    # a float32 sum of at most K kept values, then one divide: at most
+    # K roundings of 2**-24 in units of the mean |kept value|
+    assert ref.gap(np.asarray(res.new_global), value, scale) <= k * 2.0 ** -24
 
 
 def test_norm_clip_weights_identity_inside_ball():

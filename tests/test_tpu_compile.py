@@ -124,3 +124,21 @@ def test_quantized_accum_compiles(spec):
     k, c, w = 16, 12_544, 384
     _compile(quantized_accum_pallas, spec((k, c, w), jnp.int8),
              spec((k, c)), spec((k, c)))
+
+
+def test_robust_round_compiles_at_the_trimmed_cell(spec):
+    """The trimmed-mean round as the benchmark's paper cell serves it on
+    one chip: 5,450 slots of 367 f32, K = 10, 816 drained rows shipped
+    as rings of 64 and gathered into 128-packet blocks; the table fold
+    and the named rank-select finalize both lower to Pallas kernels."""
+    s, k, rows, b = 5_450, 10, 816, 128
+    text = ec._robust_round_device.lower(
+        spec((rows, b), jnp.int32), spec((rows, b)),
+        spec((rows * B, W_F32)), spec((rows, b), jnp.int32), None,
+        spec((s * W_F32,)), None, None, payload=W_F32,
+        n_params=s * W_F32, n_slots=s, n_clients=k, use_pallas=True,
+        block_slots=8, block_pkts=b, mix_alpha=0.0, interpret=False,
+        median=False, beta=0.1).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert '"kernel":"robust_finalize"' in text
+    assert '"kernel":"fold_f32"' in text

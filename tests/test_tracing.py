@@ -7,8 +7,8 @@ stragglers are served through ``run_engine_round(EngineConfig(
 compile=True))`` under the profiler, and the trace's host spans are
 read back: each phase opens a fixed number of times a round, nests in
 its parent, and opens as often in a round ten times the size (a span
-per event would not).  The fold kernels are exported for TPU and their
-custom call must carry the kernel's name.
+per event would not).  The fold kernels and the robust finalize are
+exported for TPU and their custom call must carry the kernel's name.
 """
 import collections
 import dataclasses
@@ -27,7 +27,8 @@ from repro.core.rounds import CLOSE_AT_FINALIZE
 from repro.core.server import (EngineConfig, make_uplink_stream,
                                run_engine_round)
 from repro.kernels.packet_scatter import (packet_scatter_accum_pallas,
-                                          packet_scatter_accum_q8_pallas)
+                                          packet_scatter_accum_q8_pallas,
+                                          robust_finalize_pallas)
 
 W_F32, W_Q8 = 367, 1464
 STRAGGLERS = 2
@@ -173,3 +174,16 @@ def test_fold_kernel_carries_its_name_for_tpu(name, kernel, w, q8):
     text = exported.mlir_module()
     assert "tpu_custom_call" in text
     assert f'kernel_metadata = "{{\\0A\\22kernel\\22:\\22{name}\\22' in text
+
+
+def test_robust_finalize_carries_its_name_for_tpu():
+    S, K = 16, 10
+    f32 = jnp.float32
+    exported = jax.export.export(jax.jit(robust_finalize_pallas),
+                                 platforms=["tpu"])(
+        jax.ShapeDtypeStruct((S, K, W_F32), f32),
+        jax.ShapeDtypeStruct((S, K), f32))
+    text = exported.mlir_module()
+    assert "tpu_custom_call" in text
+    assert ('kernel_metadata = "{\\0A\\22kernel\\22:'
+            '\\22robust_finalize\\22' in text)
