@@ -26,7 +26,9 @@ all-reduce.
 
 Each phase prints one JSON line: its first call's seconds, the backend
 compile seconds and persistent-cache hits within that call, the warm
-round's seconds and the error against its reference.  The last line is
+round's seconds, the error against its reference, and the share of the
+dense sweep's slot-block visits the fold grid makes on the round's
+drain schedule (before any shard split).  The last line is
 ``{"ok": true, "device": {...}}``.  Any mismatch or exception exits
 nonzero, and so does a run that finds no TPU or whose round does not
 hold the Pallas kernel (``tpu_custom_call``).
@@ -229,14 +231,33 @@ def f32_bound(s: Smoke, x: np.ndarray) -> float:
     return float(s.k * np.finfo(np.float32).eps * np.abs(x).max())
 
 
+def visit_share(cfg, events) -> float:
+    """Share of the dense sweep's slot-block visits that the fold's
+    hit-block grid makes on this round's drain schedule, before any
+    shard split (``fold_block_visits``; the table modes fold the combined
+    ``slot·K + client`` index into ``S·K`` rows).  A count, not a time."""
+    from repro.core.engine_compiled import (TABLE_MODES,
+                                            _combined_table_sched,
+                                            demux_events)
+    from repro.kernels.packet_scatter import fold_block_visits
+    sched, _, _ = demux_events(cfg, events)
+    n_rows = cfg.n_slots
+    if cfg.agg_mode in TABLE_MODES:
+        sched = _combined_table_sched(sched, cfg.n_clients)
+        n_rows *= cfg.n_clients
+    visited, dense = fold_block_visits(sched.idx, n_rows)
+    return visited / dense
+
+
 def emit(line: dict) -> None:
     print(json.dumps(line), flush=True)
 
 
-def phase_line(phase, cfg, res, times, err, **extra):
+def phase_line(phase, cfg, res, times, err, events, **extra):
     return dict(phase=phase, n_params=cfg.n_params, payload=cfg.payload,
                 slots=cfg.n_slots, accepted=res.stats.data_enqueued,
-                **times, max_err=err, **extra)
+                **times, max_err=err,
+                fold_visit_share=visit_share(cfg, events), **extra)
 
 
 def phase_a(s: Smoke):
@@ -268,8 +289,9 @@ def phase_a(s: Smoke):
     err = float(np.abs(got_n - want_n).max())
     bound = f32_bound(s, s.normal)
     check(err <= bound, f"phase a: error {err} above bound {bound}")
-    emit(phase_line("a_exact_f32", cfg, res, times, err, bound=bound,
-                    int_bitwise=True, int_ulps_vs_numpy_divide=int_ulps,
+    emit(phase_line("a_exact_f32", cfg, res, times, err, ev_int,
+                    bound=bound, int_bitwise=True,
+                    int_ulps_vs_numpy_divide=int_ulps,
                     fallback_slots=int((counts == 0).sum())))
     return ev, got_n
 
@@ -289,7 +311,7 @@ def phase_b(s: Smoke, ev, exact_global):
           f"(max err {err})")
     rel = float(np.linalg.norm(got - exact_global)
                 / np.linalg.norm(exact_global))
-    emit(phase_line("b_approx_f32", cfg, res, times, err,
+    emit(phase_line("b_approx_f32", cfg, res, times, err, ev,
                     twin_bitwise=True, rel_l2_vs_exact=rel))
 
 
@@ -312,7 +334,7 @@ def phase_c(s: Smoke):
                                         np.asarray(want.counts)),
           f"phase c: q8 round not bitwise equal to the host-decoded "
           f"f32 round (max err {err})")
-    emit(phase_line("c_exact_q8", cfg, res, times, err,
+    emit(phase_line("c_exact_q8", cfg, res, times, err, ev_q8,
                     host_decoded_bitwise=True))
 
 
@@ -330,7 +352,7 @@ def phase_d(s: Smoke):
     err = float(np.abs(np.asarray(res.new_global) - want).max())
     bound = f32_bound(s, s.normal)
     check(err <= bound, f"phase d: error {err} above bound {bound}")
-    emit(phase_line("d_trimmed_mean_f32", cfg, res, times, err,
+    emit(phase_line("d_trimmed_mean_f32", cfg, res, times, err, ev,
                     bound=bound, trim_beta=cfg.trim_beta))
 
 
@@ -345,7 +367,7 @@ def phases_sharded(s: Smoke):
     base_cfg = s.cfg(PAYLOAD_F32)
     s.check_round(base_cfg, ev, s.prev_int)
     base, times = serve(base_cfg, s.ints, s.prev_int, ev)
-    emit(phase_line("shards_1", base_cfg, base, times, 0.0))
+    emit(phase_line("shards_1", base_cfg, base, times, 0.0, ev))
     layouts = [("shards_4", dict(shards=4), worker_ctx(4), (4,)),
                ("hosts_2_shards_2", dict(hosts=2, shards=2),
                 host_ctx(2, 2), (2, 2))]
@@ -362,7 +384,7 @@ def phases_sharded(s: Smoke):
                 and np.array_equal(np.asarray(res.counts),
                                    np.asarray(base.counts)))
         check(same, f"{name}: not bitwise equal to shards=1")
-        emit(phase_line(name, cfg, res, times, 0.0, mesh=list(shape),
+        emit(phase_line(name, cfg, res, times, 0.0, ev, mesh=list(shape),
                         bitwise_vs_shards_1=True))
 
 

@@ -17,9 +17,13 @@ parameter buffer (§4.1).  Two kernels cover the two server designs:
 ``packet_scatter_accum_pallas``
     The worker loop (§3.2.2): a drained ring batch of packets is *added*
     into a live ``(n_slots, W)`` accumulator with per-slot arrival
-    counts.  The grid is (slot-block, packet-block) with the packet sweep
-    innermost; the accumulator block is revisited across the sweep and
-    carries the running sum in VMEM (DESIGN.md §3).  Packets are routed
+    counts.  The grid is (hit block, packet block) with the packet sweep
+    innermost: the sorted distinct slot blocks the batch's packets hit,
+    computed on the device and scalar-prefetched, so a batch costs the
+    blocks it touches and not the accumulator's height.  Blocks the grid
+    never visits keep their values through the aliased output; a visited
+    block is loaded at its first step and carries the running sum in
+    VMEM for the rest of its run (DESIGN.md §3).  Packets are routed
     by a one-hot (slot × packet) matrix multiply, so the scatter runs on
     the MXU instead of serializing per-packet stores.  Two modes:
 
@@ -44,7 +48,8 @@ bitwise jnp twin of one kernel call (the scan body on non-TPU
 backends, where the interpreted grid would unroll per batch), and
 ``packet_scatter_accum_scan`` drives a dense (n_batches, B) drain
 schedule through either body as one ``lax.scan`` with the accumulator
-carried in place.
+carried in place.  ``fold_block_visits`` counts, for a whole schedule,
+the slot blocks the hit-block grid visits against the dense sweep's.
 """
 from __future__ import annotations
 
@@ -114,64 +119,121 @@ def packet_scatter_pallas(packets: jnp.ndarray, idx: jnp.ndarray,
       ).reshape(n_slots, W)
 
 
-def _scatter_accum_body(idx_ref, w_ref, pkt, acc_in_ref, cnt_in_ref,
-                        acc_ref, cnt_ref, *, exact: bool):
-    """Shared grid-step body: route an f32 packet block into the live
-    accumulator.  ``pkt`` (BN, W) f32 is already wire-decoded — the f32
-    kernel passes the payload block through, the q8 kernel dequantizes
-    rows first — so both wire formats share one accumulate dataflow.
+def _hit_blocks(idx: jnp.ndarray, n_blocks: int, block_slots: int):
+    """The slot blocks a batch's valid packets hit, as the fold grid's
+    block list (DESIGN.md §3).
 
-    idx/w (1, BN); acc blocks (BS, W); cnt blocks (BS, 1).  The acc/cnt
-    output blocks are revisited across the (innermost) packet-block
-    dimension: copied from the live accumulator at the first packet
-    block, then updated in VMEM for the rest of the sweep.
+    idx (..., N) int32 slot rows; entries outside ``[0, n_blocks ·
+    block_slots)`` (ring padding) hit nothing.  Returns ``(blk (..., G)
+    int32, n_hit (...,) int32)`` with ``G = min(N, n_blocks)`` — N
+    packets hit at most N blocks — so the grid's length follows from
+    shapes alone.  The first ``n_hit`` entries of ``blk`` are the
+    distinct hit blocks in ascending order, the rest repeat the last of
+    them (block 0 when none is hit), so the padded grid steps stay on a
+    block already visited.  Vectorised over the leading axes: a whole
+    ``(n_rows, B)`` schedule costs two sorts.
     """
-    j = pl.program_id(1)
+    n_grid = min(idx.shape[-1], n_blocks)
+    valid = (idx >= 0) & (idx < n_blocks * block_slots)
+    b = jnp.sort(jnp.where(valid, idx // block_slots, n_blocks), axis=-1)
+    first = jnp.concatenate(
+        [jnp.ones_like(b[..., :1], bool), b[..., 1:] != b[..., :-1]],
+        axis=-1) & (b < n_blocks)
+    n_hit = jnp.sum(first, axis=-1, dtype=jnp.int32)
+    # distinct blocks to the front, the sentinel n_blocks behind them
+    d = jnp.sort(jnp.where(first, b, n_blocks), axis=-1)[..., :n_grid]
+    last = jnp.max(jnp.where(first, b, 0), axis=-1, keepdims=True)
+    return jnp.where(d < n_blocks, d, last).astype(jnp.int32), n_hit
 
-    @pl.when(j == 0)
+
+def fold_block_visits(sched_idx, n_slots: int, block_slots: int = 8):
+    """Engagement counter of the hit-block fold grid, for a whole
+    ``(n_rows, B)`` drain schedule: ``(visited, dense)``, the slot
+    blocks the grid visits with work and the blocks the dense sweep of
+    every block for every batch visited.  ``n_slots`` is the
+    accumulator's height before its pad to whole blocks.  Built from the
+    wrapper's own ``_hit_blocks``; a count for reports, on no hot path.
+    """
+    idx = jnp.asarray(sched_idx, jnp.int32)
+    n_blocks = -(-n_slots // block_slots)
+    _, n_hit = _hit_blocks(idx, n_blocks, block_slots)
+    n_rows = idx.size // idx.shape[-1]
+    return int(jnp.sum(n_hit)), n_rows * n_blocks
+
+
+def _scatter_accum_body(blk_ref, nhit_ref, idx_ref, w_ref, load_pkt,
+                        acc_in_ref, cnt_in_ref, acc_ref, cnt_ref, *,
+                        exact: bool):
+    """Shared grid-step body: route an f32 packet block into one slot
+    block of the live accumulator.  ``load_pkt()`` gives the (BN, W) f32
+    packet block already wire-decoded — the f32 kernel passes the
+    payload block through, the q8 kernel dequantizes rows first — so
+    both wire formats share one accumulate dataflow.
+
+    Step (g, j) works on slot block ``blk_ref[g]`` (the hit-block list,
+    ``_hit_blocks``) and packet block j; idx/w (1, BN), acc blocks
+    (BS, W), cnt blocks (BS, 1).  The acc/cnt output block is revisited
+    across a run of steps on one block: loaded from the live
+    accumulator at the run's first step (even where that step adds
+    nothing, so the all-inert batch and the padded tail write back the
+    block's own values, never uninitialised VMEM), then updated in VMEM.
+    Only steps ``g < n_hit`` accumulate.
+    """
+    g, j = pl.program_id(0), pl.program_id(1)
+    n_hit = nhit_ref[0]
+
+    # the list is sorted and distinct up to n_hit, then repeats its last
+    # entry: step g starts a run of one block iff g == 0 or g < n_hit
+    @pl.when((j == 0) & ((g == 0) | (g < n_hit)))
     def _load_accumulator():
         acc_ref[...] = acc_in_ref[...]
         cnt_ref[...] = cnt_in_ref[...]
 
-    BS = acc_ref.shape[0]
-    base = pl.program_id(0) * BS
-    rows = jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0) + base
-    hits = idx_ref[...] == rows                       # (BS, BN) bool
-    w = w_ref[...]                                    # (1, BN) f32
-    whot = hits.astype(jnp.float32) * w               # weighted one-hot
-    # the divisor sees every arrival, in both modes (§3.2.2 count rule)
-    cnt_ref[...] += jnp.sum(whot, axis=1, keepdims=True)
+    @pl.when(g < n_hit)
+    def _accumulate():
+        BS = acc_ref.shape[0]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (BS, 1), 0) + \
+            blk_ref[g] * BS
+        hits = idx_ref[...] == rows                   # (BS, BN) bool
+        w = w_ref[...]                                # (1, BN) f32
+        whot = hits.astype(jnp.float32) * w           # weighted one-hot
+        # the divisor sees every arrival, in both modes (§3.2.2 count rule)
+        cnt_ref[...] += jnp.sum(whot, axis=1, keepdims=True)
+        pkt = load_pkt()
 
-    if exact:
-        acc_ref[...] += jnp.dot(whot, pkt, precision=_F32_DOT,
-                                preferred_element_type=jnp.float32)
-    else:
-        # Lock-free race, deterministic form: each writer reads the
-        # call-entry snapshot (acc_in), so of all batch packets hitting a
-        # slot only the last write survives — earlier adds are lost, the
-        # paper's lost-update bias.
-        valid = hits & (w > 0)
-        colpos = jax.lax.broadcasted_iota(jnp.int32, valid.shape, 1) + 1
-        lastcol = jnp.max(jnp.where(valid, colpos, 0), axis=1,
-                          keepdims=True)              # (BS, 1); 0 = no hit
-        lasthot = (colpos == lastcol) & valid
-        contrib = jnp.dot(lasthot.astype(jnp.float32) * w, pkt,
-                          precision=_F32_DOT,
-                          preferred_element_type=jnp.float32)
-        acc_ref[...] = jnp.where(lastcol > 0, acc_in_ref[...] + contrib,
-                                 acc_ref[...])
+        if exact:
+            acc_ref[...] += jnp.dot(whot, pkt, precision=_F32_DOT,
+                                    preferred_element_type=jnp.float32)
+        else:
+            # Lock-free race, deterministic form: each writer reads the
+            # call-entry snapshot (acc_in), so of all batch packets
+            # hitting a slot only the last write survives — earlier adds
+            # are lost, the paper's lost-update bias.
+            valid = hits & (w > 0)
+            colpos = jax.lax.broadcasted_iota(jnp.int32, valid.shape, 1) + 1
+            lastcol = jnp.max(jnp.where(valid, colpos, 0), axis=1,
+                              keepdims=True)          # (BS, 1); 0 = no hit
+            lasthot = (colpos == lastcol) & valid
+            contrib = jnp.dot(lasthot.astype(jnp.float32) * w, pkt,
+                              precision=_F32_DOT,
+                              preferred_element_type=jnp.float32)
+            acc_ref[...] = jnp.where(lastcol > 0, acc_in_ref[...] + contrib,
+                                     acc_ref[...])
 
 
-def _scatter_accum_kernel(idx_ref, w_ref, pkt_ref, acc_in_ref, cnt_in_ref,
-                          acc_ref, cnt_ref, *, exact: bool):
+def _scatter_accum_kernel(blk_ref, nhit_ref, idx_ref, w_ref, pkt_ref,
+                          acc_in_ref, cnt_in_ref, acc_ref, cnt_ref, *,
+                          exact: bool):
     """f32 wire format: the payload block is the packet block."""
-    _scatter_accum_body(idx_ref, w_ref, pkt_ref[...].astype(jnp.float32),
+    _scatter_accum_body(blk_ref, nhit_ref, idx_ref, w_ref,
+                        lambda: pkt_ref[...].astype(jnp.float32),
                         acc_in_ref, cnt_in_ref, acc_ref, cnt_ref,
                         exact=exact)
 
 
-def _scatter_accum_q8_kernel(idx_ref, w_ref, s_ref, pkt_ref, acc_in_ref,
-                             cnt_in_ref, acc_ref, cnt_ref, *, exact: bool):
+def _scatter_accum_q8_kernel(blk_ref, nhit_ref, idx_ref, w_ref, s_ref,
+                             pkt_ref, acc_in_ref, cnt_in_ref, acc_ref,
+                             cnt_ref, *, exact: bool):
     """q8 wire format: fused dequantize-then-accumulate.
 
     ``s_ref`` (BN, 1) carries the per-packet symmetric scales; rows are
@@ -182,9 +244,84 @@ def _scatter_accum_q8_kernel(idx_ref, w_ref, s_ref, pkt_ref, acc_in_ref,
     kernel, because the per-element IEEE ops are identical (f32 multiply
     is not associative across the dot contraction).
     """
-    pkt = pkt_ref[...].astype(jnp.float32) * s_ref[...]
-    _scatter_accum_body(idx_ref, w_ref, pkt, acc_in_ref, cnt_in_ref,
-                        acc_ref, cnt_ref, exact=exact)
+    _scatter_accum_body(
+        blk_ref, nhit_ref, idx_ref, w_ref,
+        lambda: pkt_ref[...].astype(jnp.float32) * s_ref[...],
+        acc_in_ref, cnt_in_ref, acc_ref, cnt_ref, exact=exact)
+
+
+def _fold_call(kernel, name: str, hit, idx, weights, wire, acc, counts, *,
+               block_slots: int, block_pkts: int, interpret: bool):
+    """One fold kernel call over the ``(hit block, packet block)`` grid.
+
+    ``hit`` is ``(blk (G,), n_hit ())`` from ``_hit_blocks``; both ride
+    as scalar-prefetch operands, so the accumulator BlockSpecs map step
+    g to slot block ``blk[g]`` and the DMA engine fetches only the hit
+    blocks.  ``wire`` holds the (N, ·) packet-block operands: the
+    payloads, and on q8 the scale column first.  Blocks the grid never
+    visits keep their HBM contents through ``input_output_aliases``.
+    """
+    blk, n_hit = hit
+    N = idx.shape[0]
+    S, W = acc.shape
+    assert N % block_pkts == 0, (N, block_pkts)
+    assert S % block_slots == 0, (S, block_slots)
+    n_pkt_blocks = N // block_pkts
+    idx2d = idx.astype(jnp.int32).reshape(n_pkt_blocks, block_pkts)
+    w2d = weights.astype(jnp.float32).reshape(n_pkt_blocks, block_pkts)
+
+    def pkt_block(g, j, blk_ref, nhit_ref):
+        return j, 0
+
+    def slot_block(g, j, blk_ref, nhit_ref):
+        return blk_ref[g], 0
+
+    slot_specs = [pl.BlockSpec((block_slots, W), slot_block),
+                  pl.BlockSpec((block_slots, 1), slot_block)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(blk.shape[0], n_pkt_blocks),
+        in_specs=([pl.BlockSpec((1, block_pkts), pkt_block)] * 2
+                  + [pl.BlockSpec((block_pkts, a.shape[1]), pkt_block)
+                     for a in wire]
+                  + slot_specs),
+        out_specs=slot_specs,
+    )
+    # operand indices count the two scalar-prefetch args
+    n_before_acc = 4 + len(wire)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((S, W), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1), jnp.float32),
+        ],
+        input_output_aliases={n_before_acc: 0, n_before_acc + 1: 1},
+        interpret=interpret,
+        metadata={"kernel": name},
+    )(blk.astype(jnp.int32), n_hit.astype(jnp.int32).reshape(1), idx2d,
+      w2d, *wire, acc.astype(jnp.float32), counts.astype(jnp.float32))
+
+
+def _fold_f32(packets, idx, weights, acc, counts, hit, *, exact: bool,
+              block_slots: int, block_pkts: int, interpret: bool):
+    return _fold_call(
+        functools.partial(_scatter_accum_kernel, exact=exact), "fold_f32",
+        hit, idx, weights, (packets,), acc, counts,
+        block_slots=block_slots, block_pkts=block_pkts, interpret=interpret)
+
+
+def _fold_q8(packets, scales, idx, weights, acc, counts, hit, *,
+             exact: bool, block_slots: int, block_pkts: int,
+             interpret: bool):
+    # scales ride as an (N, 1) column so the block lands as (BN, 1) and
+    # broadcasts against the (BN, W) payload with no in-kernel transpose
+    wire = (scales.astype(jnp.float32).reshape(-1, 1),
+            packets.astype(jnp.int8))
+    return _fold_call(
+        functools.partial(_scatter_accum_q8_kernel, exact=exact), "fold_q8",
+        hit, idx, weights, wire, acc, counts,
+        block_slots=block_slots, block_pkts=block_pkts, interpret=interpret)
 
 
 def packet_scatter_accum_pallas(packets: jnp.ndarray, idx: jnp.ndarray,
@@ -207,39 +344,19 @@ def packet_scatter_accum_pallas(packets: jnp.ndarray, idx: jnp.ndarray,
     their accumulator value; duplicates add in ``exact`` mode and
     resolve last-writer-wins against the call-entry snapshot in
     ``approx`` mode, while counts always see every weighted arrival.
+
+    The grid is ``(hit block, packet block)``: the sorted distinct slot
+    blocks the batch's packets hit, computed here on the device and
+    padded to ``min(N, S // block_slots)`` steps, so a batch costs the
+    blocks it touches, not the accumulator's height.  Unvisited blocks
+    keep their values by aliasing; each visited block is loaded at its
+    first step.  Bitwise the dense sweep of every block on finite
+    payloads: an unhit block only ever added an exact zero.
     """
-    N, W = packets.shape
-    S = acc.shape[0]
-    assert N % block_pkts == 0, (N, block_pkts)
-    assert S % block_slots == 0, (S, block_slots)
-    n_pkt_blocks = N // block_pkts
-    idx2d = idx.astype(jnp.int32).reshape(n_pkt_blocks, block_pkts)
-    w2d = weights.astype(jnp.float32).reshape(n_pkt_blocks, block_pkts)
-    grid = (S // block_slots, n_pkt_blocks)
-    kernel = functools.partial(_scatter_accum_kernel, exact=exact)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_pkts), lambda s, j: (j, 0)),
-            pl.BlockSpec((1, block_pkts), lambda s, j: (j, 0)),
-            pl.BlockSpec((block_pkts, W), lambda s, j: (j, 0)),
-            pl.BlockSpec((block_slots, W), lambda s, j: (s, 0)),
-            pl.BlockSpec((block_slots, 1), lambda s, j: (s, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_slots, W), lambda s, j: (s, 0)),
-            pl.BlockSpec((block_slots, 1), lambda s, j: (s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, W), jnp.float32),
-            jax.ShapeDtypeStruct((S, 1), jnp.float32),
-        ],
-        input_output_aliases={3: 0, 4: 1},
-        interpret=interpret,
-        metadata={"kernel": "fold_f32"},
-    )(idx2d, w2d, packets, acc.astype(jnp.float32),
-      counts.astype(jnp.float32))
+    hit = _hit_blocks(idx, acc.shape[0] // block_slots, block_slots)
+    return _fold_f32(packets, idx, weights, acc, counts, hit, exact=exact,
+                     block_slots=block_slots, block_pkts=block_pkts,
+                     interpret=interpret)
 
 
 def packet_scatter_accum_q8_pallas(packets: jnp.ndarray,
@@ -255,45 +372,13 @@ def packet_scatter_accum_q8_pallas(packets: jnp.ndarray,
     packets (N, W) **int8** wire payloads; scales (N,) f32 per-packet
     symmetric dequant scales (0 for ring padding).  Dequantization is
     fused into the accumulate grid step, so no f32 copy of the uplink
-    ever materializes outside VMEM.  Same contract and same numerics as
+    ever materializes outside VMEM.  Same contract, grid and numerics as
     dequantizing host-side and calling the f32 kernel.
     """
-    N, W = packets.shape
-    S = acc.shape[0]
-    assert N % block_pkts == 0, (N, block_pkts)
-    assert S % block_slots == 0, (S, block_slots)
-    n_pkt_blocks = N // block_pkts
-    idx2d = idx.astype(jnp.int32).reshape(n_pkt_blocks, block_pkts)
-    w2d = weights.astype(jnp.float32).reshape(n_pkt_blocks, block_pkts)
-    # scales ride as an (N, 1) column so the block lands as (BN, 1) and
-    # broadcasts against the (BN, W) payload with no in-kernel transpose
-    s2d = scales.astype(jnp.float32).reshape(N, 1)
-    grid = (S // block_slots, n_pkt_blocks)
-    kernel = functools.partial(_scatter_accum_q8_kernel, exact=exact)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_pkts), lambda s, j: (j, 0)),
-            pl.BlockSpec((1, block_pkts), lambda s, j: (j, 0)),
-            pl.BlockSpec((block_pkts, 1), lambda s, j: (j, 0)),
-            pl.BlockSpec((block_pkts, W), lambda s, j: (j, 0)),
-            pl.BlockSpec((block_slots, W), lambda s, j: (s, 0)),
-            pl.BlockSpec((block_slots, 1), lambda s, j: (s, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_slots, W), lambda s, j: (s, 0)),
-            pl.BlockSpec((block_slots, 1), lambda s, j: (s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, W), jnp.float32),
-            jax.ShapeDtypeStruct((S, 1), jnp.float32),
-        ],
-        input_output_aliases={4: 0, 5: 1},
-        interpret=interpret,
-        metadata={"kernel": "fold_q8"},
-    )(idx2d, w2d, s2d, packets.astype(jnp.int8), acc.astype(jnp.float32),
-      counts.astype(jnp.float32))
+    hit = _hit_blocks(idx, acc.shape[0] // block_slots, block_slots)
+    return _fold_q8(packets, scales, idx, weights, acc, counts, hit,
+                    exact=exact, block_slots=block_slots,
+                    block_pkts=block_pkts, interpret=interpret)
 
 
 def staleness_weights(weights: jnp.ndarray, staleness: jnp.ndarray,
@@ -621,8 +706,9 @@ def packet_scatter_accum_scan(sched_idx: jnp.ndarray, sched_w: jnp.ndarray,
     carried through the scan — XLA keeps the carry buffers in place, so
     no per-drain (S, W) reallocation happens.  ``use_pallas`` selects
     the Pallas grid kernel (the production TPU body; S must then be a
-    multiple of ``block_slots`` and B of ``block_pkts``) vs the jnp
-    twin; both implement the same DESIGN.md §3 contract per batch.
+    multiple of ``block_slots`` and B of ``block_pkts``; every row's
+    hit-block list is computed before the scan) vs the jnp twin; both
+    implement the same DESIGN.md §3 contract per batch.
 
     When ``sched_scales`` (n_batches, B) is given, sched_pk carries the
     int8 wire payloads and each batch dequantizes inside the scan body
@@ -630,21 +716,24 @@ def packet_scatter_accum_scan(sched_idx: jnp.ndarray, sched_w: jnp.ndarray,
     as a whole-round tensor (DESIGN.md §9).
     """
     q8 = sched_scales is not None
+    xs = ((sched_idx, sched_w, sched_scales, sched_pk) if q8
+          else (sched_idx, sched_w, sched_pk))
     if use_pallas:
+        # every batch's hit-block list in one vectorised pass before the
+        # scan, fed to the steps in xs: no per-batch sort on the chip
+        xs = xs + _hit_blocks(sched_idx, acc.shape[0] // block_slots,
+                              block_slots)
+        kw = dict(exact=exact, block_slots=block_slots,
+                  block_pkts=block_pkts, interpret=interpret)
+
         def step(carry, batch):
             a, c = carry
             if q8:
-                bidx, bw, bsc, bpk = batch
-                a, c = packet_scatter_accum_q8_pallas(
-                    bpk, bsc, bidx, bw, a, c, exact=exact,
-                    block_slots=block_slots, block_pkts=block_pkts,
-                    interpret=interpret)
+                bidx, bw, bsc, bpk, blk, n_hit = batch
+                a, c = _fold_q8(bpk, bsc, bidx, bw, a, c, (blk, n_hit), **kw)
             else:
-                bidx, bw, bpk = batch
-                a, c = packet_scatter_accum_pallas(
-                    bpk, bidx, bw, a, c, exact=exact,
-                    block_slots=block_slots,
-                    block_pkts=block_pkts, interpret=interpret)
+                bidx, bw, bpk, blk, n_hit = batch
+                a, c = _fold_f32(bpk, bidx, bw, a, c, (blk, n_hit), **kw)
             return (a, c), None
     else:
         def step(carry, batch):
@@ -658,8 +747,6 @@ def packet_scatter_accum_scan(sched_idx: jnp.ndarray, sched_w: jnp.ndarray,
                 a, c = packet_scatter_accum_batch_jnp(bpk, bidx, bw, a, c,
                                                       exact=exact)
             return (a, c), None
-    xs = ((sched_idx, sched_w, sched_scales, sched_pk) if q8
-          else (sched_idx, sched_w, sched_pk))
     (acc, counts), _ = jax.lax.scan(step, (acc, counts), xs)
     return acc, counts
 

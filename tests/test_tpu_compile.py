@@ -142,3 +142,49 @@ def test_robust_round_compiles_at_the_trimmed_cell(spec):
     assert "tpu_custom_call" in text
     assert '"kernel":"robust_finalize"' in text
     assert '"kernel":"fold_f32"' in text
+
+
+# the fold's hit-block grid at the benchmark cells' accumulators, with a
+# ring of 64 padded to the 128-packet block (DESIGN.md §3)
+B_CELL = 128
+
+
+def _compile_named(name, fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f'"kernel":"{name}"' in text
+
+
+@pytest.mark.parametrize("s", [5_456, 54_504],
+                         ids=["paper_accumulator", "trimmed_table"])
+def test_hit_block_fold_f32_compiles(spec, s):
+    _compile_named("fold_f32", lambda p, i, w, a, c:
+                   packet_scatter_accum_pallas(p, i, w, a, c),
+                   spec((B_CELL, W_F32)), spec((B_CELL,), jnp.int32),
+                   spec((B_CELL,)), spec((s, W_F32)), spec((s, 1)))
+
+
+def test_hit_block_fold_q8_compiles(spec):
+    s = 1_368                     # the xdev cell's 1,367 slots, padded
+    _compile_named("fold_q8", lambda p, sc, i, w, a, c:
+                   packet_scatter_accum_q8_pallas(p, sc, i, w, a, c),
+                   spec((B_CELL, W_Q8), jnp.int8), spec((B_CELL,)),
+                   spec((B_CELL,), jnp.int32), spec((B_CELL,)),
+                   spec((s, W_Q8)), spec((s, 1)))
+
+
+def test_pallas_scan_lists_hit_blocks_before_the_scan(spec):
+    """The scan's hit-block lists come from one sort of the whole
+    schedule ahead of the loop, none inside its body, and the loop's
+    body is the named kernel."""
+    rows, s = 16, 5_456
+    args = (spec((rows, B_CELL), jnp.int32), spec((rows, B_CELL)),
+            spec((rows, B_CELL, W_F32)), spec((s, W_F32)), spec((s, 1)))
+
+    def fold(i, w, p, a, c):
+        return packet_scatter_accum_scan(i, w, p, a, c, use_pallas=True)
+    jaxpr = jax.make_jaxpr(fold)(*args).jaxpr
+    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert "sort" in str(jaxpr)
+    assert "sort" not in str(scan.params["jaxpr"])
+    _compile_named("fold_f32", fold, *args)
