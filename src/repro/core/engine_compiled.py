@@ -12,10 +12,9 @@ that pipeline and compiles the *execution*:
    drained ring batch, padded with inert ``idx = -1`` / ``weight = 0``
    entries, and a ``src`` column naming the payload row each entry
    folds.  The payload rows themselves ship once, in arrival order
-   (``n_batches * ring_capacity`` rows with a zero tail), and the
-   single-chip round expands them to the ``(n_batches, B, W)`` fold
-   input on the device; the shard, host and async paths, which regroup
-   batches on the host, expand them there (``expand_payloads``).  The
+   (``n_batches * ring_capacity`` rows with a zero tail), and every
+   round, on any topology and in async mode too, expands them to the
+   ``(n_batches, B, W)`` fold input on the device (``_take_rows``).  The
    schedule reproduces the eager engine's batching exactly: round-robin
    or slot demux onto ``n_workers`` rings, a drain whenever a ring
    reaches capacity (in arrival order), and the END flush of partial
@@ -39,20 +38,23 @@ that pipeline and compiles the *execution*:
    workers (§3.2).
 
 4. **Sharding** (``EngineConfig(shards=N)``, DESIGN.md §7): the drain
-   schedule is demuxed per shard by ring ownership
-   (``shard_schedule``) and each shard folds its batches into
-   shard-local ``(total, counts)`` partials — the DPU's per-core
-   partial sums — combined by one ``psum`` over the ``'worker'``
-   device mesh (``runtime.sharding.worker_mesh``) before the fused END
-   divide; a vmap emulation covers platforms with fewer devices,
-   bitwise identically.
+   batches are split per shard by ring ownership (``leaf_layout``),
+   each shard ships only its own payload rows and ``src`` column, and
+   folds its batches into shard-local ``(total, counts)`` partials —
+   the DPU's per-core partial sums — combined by one ``psum`` over the
+   ``'worker'`` device mesh (``runtime.sharding.worker_mesh``) before
+   the fused END divide; a vmap emulation covers platforms with fewer
+   devices, bitwise identically.
 
 5. **Hierarchy** (``EngineConfig(hosts=H)``, DESIGN.md §12): the
    accepted arrivals are partitioned by contiguous client-range
    ownership (``partition_schedule_by_host``), each leaf host
    re-demuxes only its own clients' packets with its own rings, the
    shard split applies within each host, and the fold combines with
-   one psum per level of the 2-D ``('host', 'worker')`` mesh.
+   one psum per level of the 2-D ``('host', 'worker')`` mesh.  One
+   host function (``leaf_layout``) and one device function
+   (``kernels.packet_scatter.fold_leaves``) carry the topology for the
+   synchronous and the async rounds alike.
 
 Invariants the tests pin (tests/test_engine_compiled.py,
 test_engine_sharded.py, test_engine_hier.py):
@@ -77,7 +79,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Iterable, List, Optional, Tuple
+from typing import Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,15 +94,15 @@ from repro.core.server import (AsyncResult, AsyncState, AsyncStats,
                                EngineConfig, EngineStats, QuorumError,
                                RoundResult, UpdateRecord,  # noqa: F401
                                check_quorum, payload_malformed)
-from repro.kernels.packet_scatter import (BLOCK_PKTS, norm_clip_weights,
-                                          packet_scatter_accum_hier,
+from repro.kernels.packet_scatter import (BLOCK_PKTS, fold_leaves,
+                                          norm_clip_weights,
                                           packet_scatter_accum_scan,
-                                          packet_scatter_accum_sharded,
                                           packet_table_scatter,
                                           robust_finalize_jnp,
                                           robust_finalize_pallas,
                                           staleness_weights)
-from repro.runtime.sharding import host_ctx, worker_ctx
+from repro.runtime.sharding import (HOST_AXIS, WORKER_AXIS,
+                                    host_worker_mesh, worker_mesh)
 
 # Profiler spans of the served round, as ``jax.profiler.TraceAnnotation``
 # (inert when no trace runs).  Each opens once or twice a round, never
@@ -139,23 +141,28 @@ def _use_pallas(cfg: EngineConfig) -> bool:
 # Demux: arrivals -> dense drain schedule
 # ---------------------------------------------------------------------------
 
+# drain-schedule row counts are bucketed to a multiple of this, so lossy
+# round-to-round batch-count jitter reuses one jit trace
+PAD_BATCHES = 8
+
+
 @dataclasses.dataclass
 class DrainSchedule:
     """Per-round drain schedule (host arrays, ready to dispatch).
 
     One row per drain batch; rows beyond ``n_batches`` (row counts are
-    bucketed to a multiple of ``pad_batches``, so lossy round-to-round
-    batch-count jitter reuses one jit trace instead of retracing) and
-    unused columns are inert: ``idx = -1`` matches no slot, weight 0 is
-    inert in sums and counts.
+    bucketed to a multiple of ``PAD_BATCHES``) and unused columns are
+    inert: ``idx = -1`` matches no slot, weight 0 is inert in sums and
+    counts.
 
     ``payloads`` holds only the rows a round ships: the accepted
     arrivals in arrival order, then a zero tail up to ``n_rows *
     ring_capacity`` rows, a length fixed by the bucketed row count so
     the shape does not move with the accepted count.  ``src`` says which
     payload row each schedule cell folds; inert cells point one past the
-    end, where a fill-mode gather reads zeros (``expand_payloads`` on
-    the host, ``_take_rows`` on the device).
+    end, where the device's fill-mode gather (``_take_rows``) reads
+    zeros.  ``leaf_layout`` splits a schedule into the same form per
+    shard or (host, shard) leaf.
     """
     idx: np.ndarray         # (n_rows, B) int32 slot rows
     weights: np.ndarray     # (n_rows, B) f32 per-arrival FedAvg weights
@@ -165,9 +172,14 @@ class DrainSchedule:
                             # inert cells hold len(payloads)
     n_batches: int          # real drain batches (rest is padding)
     n_packets: int          # accepted arrivals scheduled
-    workers: Optional[np.ndarray] = None   # (n_rows,) owning worker ring
-                                           # per batch (-1 for padding);
-                                           # shard_schedule keys on it
+    workers: np.ndarray     # (n_rows,) owning worker ring per batch (-1
+                            # for padding); the shard split keys on it
+    arrivals: tuple         # the accepted arrival-order columns this
+                            # schedule was built from: (slots, weights,
+                            # payloads, scales, staleness, clients) —
+                            # cheap references (payloads is a view of the
+                            # shipped rows), kept so the hierarchical
+                            # path can re-demux per host (DESIGN.md §12)
     scales: Optional[np.ndarray] = None    # (n_rows, B) f32 per-packet
                                            # q8 dequant scales (0 inert);
                                            # None on the f32 wire path
@@ -180,35 +192,13 @@ class DrainSchedule:
                                            # robust table modes' combined
                                            # index needs it (DESIGN.md
                                            # §11); None when untracked
-    arrivals: Optional[tuple] = None       # the accepted arrival-order
-                                           # columns this schedule was
-                                           # built from: (slots, weights,
-                                           # payloads, scales, staleness,
-                                           # clients) — cheap references
-                                           # (payloads is a view of the
-                                           # shipped rows), kept so the
-                                           # hierarchical path can
-                                           # re-demux per host
-                                           # (DESIGN.md §12)
-
-
-def expand_payloads(sched: DrainSchedule) -> np.ndarray:
-    """The ``(n_rows, B, W)`` payload tensor the fold reads, built on
-    the host: shipped row ``src[r, c]`` at cell ``(r, c)``, zeros at the
-    inert cells.  The paths that regroup batches on the host (shards,
-    hosts, async windows) fold this form."""
-    pk, src = sched.payloads, sched.src
-    out = np.zeros(src.shape + pk.shape[1:], pk.dtype)
-    live = src < pk.shape[0]
-    out[live] = pk[src[live]]
-    return out
 
 
 def build_drain_schedule(slots: np.ndarray, weights: np.ndarray,
                          payloads: np.ndarray, *, n_workers: int,
                          ring_capacity: int, ring_assign: str = "rr",
                          block_pkts: int = BLOCK_PKTS,
-                         pad_batches: int = 8,
+                         pad_batches: int = PAD_BATCHES,
                          scales: Optional[np.ndarray] = None,
                          staleness: Optional[np.ndarray] = None,
                          clients: Optional[np.ndarray] = None
@@ -244,7 +234,8 @@ def build_drain_schedule(slots: np.ndarray, weights: np.ndarray,
 
 def _drain_layout(slots: np.ndarray, weights: np.ndarray, width: int, *,
                   n_workers: int, ring_capacity: int, ring_assign: str = "rr",
-                  block_pkts: int = BLOCK_PKTS, pad_batches: int = 8,
+                  block_pkts: int = BLOCK_PKTS,
+                  pad_batches: int = PAD_BATCHES,
                   scales: Optional[np.ndarray] = None,
                   staleness: Optional[np.ndarray] = None,
                   clients: Optional[np.ndarray] = None) -> DrainSchedule:
@@ -308,62 +299,9 @@ def _drain_layout(slots: np.ndarray, weights: np.ndarray, width: int, *,
         cl[row, col] = clients
     row_worker = np.full(n_rows, -1, np.int64)
     row_worker[rank] = uniq // (n + 1)            # batch key -> its worker
-    return DrainSchedule(idx, w, pk, src, int(nb), n, row_worker, sc, st, cl,
+    return DrainSchedule(idx, w, pk, src, int(nb), n, row_worker,
                          (slots, weights, pk[:n], scales, staleness,
-                          clients))
-
-
-def shard_schedule(sched: DrainSchedule, n_shards: int, *,
-                   pad_batches: int = 8
-                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                              Optional[np.ndarray], Optional[np.ndarray]]:
-    """Demux a round's drain schedule per shard (DESIGN.md §7).
-
-    Shard ``s`` owns the drain batches of worker rings ``w`` with
-    ``w % n_shards == s`` — the paper's static ring→core pinning — so a
-    drain batch (and with it approx mode's last-writer-wins window)
-    lives entirely on one shard.  Batch composition is *unchanged* from
-    the unsharded schedule; only the fold of batches into accumulators
-    is regrouped, which is what keeps any shard count bitwise identical
-    to the unsharded engine on integer-valued payloads (both modes are
-    additive across batches).
-
-    Returns ``(idx, weights, payloads, scales, staleness)`` with a
-    leading ``(n_shards,)`` axis, payloads expanded on the host to
-    ``(n_shards, rows, B, W)`` (``scales`` is None on the f32 wire
-    path, ``staleness`` on synchronous rounds); shards are padded to a
-    common row count (bucketed to a multiple of ``pad_batches`` so
-    round-to-round jitter reuses one jit trace) with inert rows, and
-    shards with no assigned ring (e.g. ``n_shards > n_workers``) are
-    entirely inert.
-    """
-    assert sched.workers is not None, "schedule predates worker tracking"
-    B = sched.idx.shape[1]
-    payloads = expand_payloads(sched)
-    W = payloads.shape[2]
-    live = sched.workers[:sched.n_batches]
-    per_shard = [np.nonzero(live % n_shards == s)[0]
-                 for s in range(n_shards)]
-    rows = max((len(p) for p in per_shard), default=0)
-    rows = max(rows, 1)
-    if pad_batches > 1:
-        rows += (-rows) % pad_batches
-    idx = np.full((n_shards, rows, B), -1, np.int32)
-    w = np.zeros((n_shards, rows, B), np.float32)
-    pk = np.zeros((n_shards, rows, B, W), payloads.dtype)
-    sc = (None if sched.scales is None
-          else np.zeros((n_shards, rows, B), np.float32))
-    st = (None if sched.staleness is None
-          else np.zeros((n_shards, rows, B), np.float32))
-    for s, p in enumerate(per_shard):
-        idx[s, :len(p)] = sched.idx[p]
-        w[s, :len(p)] = sched.weights[p]
-        pk[s, :len(p)] = payloads[p]
-        if sc is not None:
-            sc[s, :len(p)] = sched.scales[p]
-        if st is not None:
-            st[s, :len(p)] = sched.staleness[p]
-    return idx, w, pk, sc, st
+                          clients), scales=sc, staleness=st, clients=cl)
 
 
 def partition_schedule_by_host(sched: DrainSchedule, n_hosts: int,
@@ -386,10 +324,10 @@ def partition_schedule_by_host(sched: DrainSchedule, n_hosts: int,
     ``DrainSchedule`` keeps its ``arrivals`` columns.
 
     Runs *before* any robust-table index rewrite (the rewrite keys on
-    the original slot/client columns) and before ``shard_schedule``
-    (ring→shard ownership applies within each host).
+    the original slot/client columns) and before the shard split
+    (ring→shard ownership applies within each host): ``leaf_layout``
+    keeps that order.
     """
-    assert sched.arrivals is not None, "schedule predates arrival tracking"
     slots, weights, payloads, scales, staleness, clients = sched.arrivals
     assert clients is not None, \
         "hierarchical demux needs a client-tracked schedule"
@@ -409,34 +347,102 @@ def partition_schedule_by_host(sched: DrainSchedule, n_hosts: int,
     return out
 
 
-def _stack_host_shards(per_host: List[Tuple]
-                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                  Optional[np.ndarray],
-                                  Optional[np.ndarray]]:
-    """Stack H per-host ``shard_schedule`` outputs (each (S, R_h, B[, W]))
-    into (H, S, R, B[, W]) arrays padded to the max row count with inert
-    rows — the leaf grid ``packet_scatter_accum_hier`` scans."""
-    H = len(per_host)
-    S, _, B = per_host[0][0].shape
-    W = per_host[0][2].shape[-1]
-    R = max(p[0].shape[1] for p in per_host)
-    idx = np.full((H, S, R, B), -1, np.int32)
-    w = np.zeros((H, S, R, B), np.float32)
-    pk = np.zeros((H, S, R, B, W), per_host[0][2].dtype)
-    sc = (None if per_host[0][3] is None
-          else np.zeros((H, S, R, B), np.float32))
-    st = (None if per_host[0][4] is None
-          else np.zeros((H, S, R, B), np.float32))
-    for h, (hi, hw, hpk, hsc, hst) in enumerate(per_host):
-        r = hi.shape[1]
-        idx[h, :, :r] = hi
-        w[h, :, :r] = hw
-        pk[h, :, :r] = hpk
-        if sc is not None:
-            sc[h, :, :r] = hsc
-        if st is not None:
-            st[h, :, :r] = hst
-    return idx, w, pk, sc, st
+class Leaves(NamedTuple):
+    """One dispatch's fold operands, laid out per leaf (DESIGN.md §7,
+    §12).
+
+    A leaf is the single chip, a worker shard, or a (host, shard) pair.
+    Every array leads with a schedule axis (one per round, one per emit
+    window for an async call), then the leaf axes ``()``, ``(shards,)``
+    or ``(hosts, shards)``.  Each leaf ships what a single-chip round
+    ships: its own accepted payload rows and then a zero tail,
+    ``R * ring_capacity`` rows for the common row count ``R``, and a
+    leaf-local ``src`` column whose inert cells point one past the end.
+    """
+    idx: np.ndarray                   # (..., R, B) int32 slot rows
+    weights: np.ndarray               # (..., R, B) f32
+    payloads: np.ndarray              # (..., R * ring_capacity, W)
+    src: np.ndarray                   # (..., R, B) int32
+    scales: Optional[np.ndarray]      # (..., R, B) f32, q8 wire only
+    staleness: Optional[np.ndarray]   # (..., R, B) f32, async only
+
+
+def _topology(cfg: EngineConfig):
+    """``(axes, sizes, mesh)`` of a config's leaves, outermost axis
+    first; ``mesh`` is None on one chip and where the CPU emulates the
+    leaves on one device (``runtime.sharding``)."""
+    if cfg.hosts > 1:
+        return ((HOST_AXIS, WORKER_AXIS), (cfg.hosts, cfg.shards),
+                host_worker_mesh(cfg.hosts, cfg.shards))
+    if cfg.shards > 1:
+        return (WORKER_AXIS,), (cfg.shards,), worker_mesh(cfg.shards)
+    return (), (), None
+
+
+def leaf_layout(cfg: EngineConfig, scheds: Sequence[DrainSchedule]
+                ) -> Leaves:
+    """Lay one dispatch's drain schedules out per leaf: the round's
+    schedule, or an async call's per-window schedules.
+
+    The order matters.  ``partition_schedule_by_host`` first re-demuxes
+    each host's own arrivals with its own rings; then the robust table
+    modes rewrite the index (it keys on the original slot and client
+    columns); then shard ``s`` takes the drain batches of the worker
+    rings ``w`` with ``w % shards == s`` — the paper's static ring→core
+    pinning, which keeps every drain batch (approx mode's last-writer-
+    wins window) whole on one shard.  Batch composition is unchanged;
+    only the fold of batches into accumulators is regrouped, which keeps
+    any factorization bitwise the single chip on integer-valued
+    payloads.  Leaves are padded with inert rows to a common row count
+    bucketed to ``PAD_BATCHES``; a leaf that owns no ring is inert.  A
+    lone schedule on one chip comes back as it is, without a copy.
+    """
+    _, sizes, _ = _topology(cfg)
+    per_sched = []
+    for sched in scheds:
+        hosts = [sched]
+        if cfg.hosts > 1:
+            hosts = partition_schedule_by_host(
+                sched, cfg.hosts, cfg.n_clients, n_workers=cfg.n_workers,
+                ring_capacity=cfg.ring_capacity,
+                ring_assign=cfg.ring_assign)
+        if cfg.agg_mode in TABLE_MODES:
+            hosts = [_combined_table_sched(h, cfg.n_clients) for h in hosts]
+        per_sched.append(hosts)
+    if not sizes and len(scheds) == 1:
+        h = per_sched[0][0]
+        return Leaves(*(None if a is None else a[None] for a in (
+            h.idx, h.weights, h.payloads, h.src, h.scales, h.staleness)))
+    leaves = [(h, np.nonzero(h.workers[:h.n_batches] % cfg.shards == k)[0])
+              for hosts in per_sched for h in hosts
+              for k in range(cfg.shards)]
+    R = max(1, max(len(rows) for _, rows in leaves))
+    R += (-R) % PAD_BATCHES
+    end = R * cfg.ring_capacity
+    first = leaves[0][0]
+    n, B = len(leaves), first.idx.shape[1]
+    idx = np.full((n, R, B), -1, np.int32)
+    w = np.zeros((n, R, B), np.float32)
+    pk = np.zeros((n, end, first.payloads.shape[1]), first.payloads.dtype)
+    src = np.full((n, R, B), end, np.int32)
+    sc = None if first.scales is None else np.zeros((n, R, B), np.float32)
+    st = (None if first.staleness is None
+          else np.zeros((n, R, B), np.float32))
+    for i, (h, rows) in enumerate(leaves):
+        r = len(rows)
+        for out, col in ((idx, h.idx), (w, h.weights), (sc, h.scales),
+                         (st, h.staleness)):
+            if out is not None:
+                out[i, :r] = col[rows]
+        # the leaf's accepted rows, renumbered from 0, then its zero tail
+        old = h.src[rows]
+        live = old < len(h.payloads)
+        k = int(live.sum())
+        pk[i, :k] = h.payloads[old[live]]
+        src[i, :r][live] = np.arange(k, dtype=np.int32)
+    lead = (len(scheds),) + sizes
+    return Leaves(*(None if a is None else a.reshape(lead + a.shape[1:])
+                    for a in (idx, w, pk, src, sc, st)))
 
 
 def approx_lost_updates(sched: DrainSchedule, n_shards: int = 1
@@ -445,14 +451,13 @@ def approx_lost_updates(sched: DrainSchedule, n_shards: int = 1
 
     Within one drained batch every same-slot arrival beyond the last
     writer is lost in approx mode, so the loss of a batch is (weighted
-    arrivals) − (distinct slots hit).  Batches are demuxed to shards by
-    ring ownership exactly as ``shard_schedule`` does, hence the
+    arrivals) − (distinct slots hit).  Batches are split to shards by
+    ring ownership exactly as ``leaf_layout`` does, hence the
     per-shard race window: each shard loses only what its own rings
     race, summing to the unsharded total — sharding splits the lost
     updates ≈ 1/n_shards per shard without changing the global race
     (EXPERIMENTS.md §Shard-scaling).
     """
-    assert sched.workers is not None
     lost = np.zeros(n_shards, np.int64)
     live = sched.workers[:sched.n_batches]
     for r in range(sched.n_batches):
@@ -650,20 +655,65 @@ def demux_events(cfg: EngineConfig, events: Iterable,
 # ---------------------------------------------------------------------------
 
 def _take_rows(rows, src):
-    """``expand_payloads`` on the device: the shipped payload rows laid
-    out as the ``(n_rows, B, W)`` fold input.  Inert cells hold
-    ``len(rows)``, past the end, where ``mode="fill"`` reads zeros (an
-    index of -1 would wrap onto the last row), so the tensor is bitwise
-    the host-expanded one."""
+    """The shipped payload rows laid out, on the device, as the
+    ``(n_rows, B, W)`` fold input.  Inert cells hold ``len(rows)``, past
+    the end, where ``mode="fill"`` reads zeros (an index of -1 would
+    wrap onto the last row)."""
     return jnp.take(rows, src, axis=0, mode="fill", fill_value=0)
+
+
+def _fold_leaf(ops: Leaves, acc, cnt, *, exact: bool, use_pallas: bool,
+               block_slots: int, block_pkts: int, interpret: bool,
+               weigh=None, table: bool = False):
+    """One leaf's fold: gather its shipped rows (``_take_rows``), weigh
+    each packet (``weigh(ops, rows) -> weights``, elementwise per packet,
+    so no regrouping of batches can change it), and run the drain scan.
+    The robust table on the jnp body folds as one flat scatter instead
+    (``packet_table_scatter``): its combined indices are unique, and the
+    scan's per-batch ``(S·K, B)`` one-hot routing is quadratic in the
+    table height; pallas keeps the blocked grid (its production body)."""
+    pk = _take_rows(ops.payloads, ops.src)
+    w = ops.weights if weigh is None else weigh(ops, pk)
+    if table and not use_pallas:
+        return packet_table_scatter(ops.idx, w, pk, acc, cnt,
+                                    sched_scales=ops.scales)
+    return packet_scatter_accum_scan(
+        ops.idx, w, pk, acc, cnt, sched_scales=ops.scales, exact=exact,
+        use_pallas=use_pallas, block_slots=block_slots,
+        block_pkts=block_pkts, interpret=interpret)
+
+
+def _mean(total, counts):
+    """The END divide: per-slot sum over its count, 0 where none came."""
+    avg = total / jnp.maximum(counts, 1e-12)[:, None]
+    return jnp.where(counts[:, None] > 0, avg, 0.0)
+
+
+def _publish(agg, have, prev_global, client_flats, down_mask, *,
+             payload: int, n_params: int, mix_alpha: float):
+    """The END step every round shares -> (new_global, new_flats|None):
+    the ``(S, W)`` aggregate depacketized, each slot without a
+    contribution (``have`` False) falling back to ``prev_global``; then,
+    when ``client_flats`` is given, the TX downlink with each client
+    keeping its own model where ``down_mask`` lost the packet, and the
+    APFL blend ``mix_alpha``."""
+    agg_flat = depacketize(agg, n_params)
+    have = expand_packet_mask(have, payload, n_params)
+    new_global = jnp.where(have, agg_flat, prev_global)
+    if client_flats is None:
+        return new_global, None
+    down_elem = expand_packet_mask(down_mask, payload, n_params)
+    new_flats = jnp.where(down_elem > 0, new_global[None, :], client_flats)
+    if mix_alpha > 0:
+        new_flats = mix_alpha * client_flats + (1 - mix_alpha) * new_flats
+    return new_global, new_flats
 
 
 @functools.partial(jax.jit,
                    static_argnames=("mode", "payload", "n_params",
                                     "use_pallas", "block_slots",
                                     "block_pkts", "mix_alpha", "interpret",
-                                    "agg_clip", "clip_tau",
-                                    "shards", "hosts", "mesh"),
+                                    "agg_clip", "clip_tau", "axes", "mesh"),
                    donate_argnums=(0, 1))
 def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_src,
                   sched_scales, prev_global, client_flats, down_mask, *,
@@ -671,8 +721,7 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_src,
                   payload: int, n_params: int, use_pallas: bool,
                   block_slots: int, block_pkts: int, mix_alpha: float,
                   interpret: bool, agg_clip: bool = False,
-                  clip_tau: float = 1.0, shards: int = 1, hosts: int = 1,
-                  mesh=None):
+                  clip_tau: float = 1.0, axes: tuple = (), mesh=None):
     """The whole round as one compiled dataflow.
 
     total (S, W) / counts (S,) are donated and carried through the drain
@@ -681,24 +730,18 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_src,
     and — when ``client_flats``/``down_mask`` are present — the TX
     downlink fallback run fused in the same call.
 
-    With ``sched_src`` present (the single-chip round) ``sched_pk``
-    holds only the shipped rows, and the ``(n_rows, B, W)`` tensor the
-    fold reads is expanded here, on the device (``_take_rows``); without
-    it ``sched_pk`` arrives expanded on the host.
+    ``sched_pk`` holds the shipped payload rows and ``sched_src`` the
+    row each schedule cell folds; each leaf expands its own on the
+    device (``_fold_leaf``).  On the q8 wire path ``sched_pk`` is int8
+    and ``sched_scales`` carries the per-packet dequant scales;
+    dequantization happens inside the scan body (DESIGN.md §9), so the
+    round's only f32 uplink form is the accumulator itself.
 
-    On the q8 wire path ``sched_pk`` is int8 and ``sched_scales``
-    carries the per-packet dequant scales; dequantization happens
-    inside the scan body (DESIGN.md §9), so the round's only f32 uplink
-    form is the accumulator itself.
-
-    With ``shards > 1`` the schedule arrays carry a leading (shards,)
-    axis and the drain scan runs per shard into shard-local partials
-    combined by one psum (DESIGN.md §7) — over the ``'worker'`` device
-    mesh when ``mesh`` is given, else emulated on one device; the END
-    divide below is fused after the combine either way.  With
-    ``hosts > 1`` the arrays carry (hosts, shards, ...) leading axes
-    and the fold runs per leaf with one psum per mesh level — worker
-    within a host, then host across hosts (DESIGN.md §12).
+    ``axes`` names the mesh axes the schedule arrays lead with (none on
+    one chip); ``fold_leaves`` folds each leaf into leaf-local partials
+    and combines them with one psum per level (DESIGN.md §7, §12) —
+    over ``mesh`` when given, else emulated on one device; the END
+    divide below is fused after the combine either way.
     """
     S = counts.shape[0]
     acc, cnt = total, counts[:, None]
@@ -706,47 +749,25 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_src,
     if pad:
         acc = jnp.pad(acc, ((0, pad), (0, 0)))
         cnt = jnp.pad(cnt, ((0, pad), (0, 0)))
-    if sched_src is not None:
-        sched_pk = _take_rows(sched_pk, sched_src)
+    weigh = None
     if agg_clip:
         # norm_clip mode (§11): bound each packet's influence before the
-        # fold — elementwise per packet, so the schedule's grouping (and
-        # any shard split) cannot change the numerics vs the eager drain
-        sched_w = norm_clip_weights(sched_w, sched_pk, tau=clip_tau,
-                                    scales=sched_scales)
-    if hosts > 1:
-        acc, cnt = packet_scatter_accum_hier(
-            sched_idx, sched_w, sched_pk, acc, cnt,
-            sched_scales=sched_scales, mesh=mesh,
-            exact=(mode == "exact"), use_pallas=use_pallas,
-            block_slots=block_slots, block_pkts=block_pkts,
-            interpret=interpret)
-    elif shards > 1:
-        acc, cnt = packet_scatter_accum_sharded(
-            sched_idx, sched_w, sched_pk, acc, cnt,
-            sched_scales=sched_scales, mesh=mesh,
-            exact=(mode == "exact"), use_pallas=use_pallas,
-            block_slots=block_slots, block_pkts=block_pkts,
-            interpret=interpret)
-    else:
-        acc, cnt = packet_scatter_accum_scan(
-            sched_idx, sched_w, sched_pk, acc, cnt,
-            sched_scales=sched_scales, exact=(mode == "exact"),
-            use_pallas=use_pallas, block_slots=block_slots,
-            block_pkts=block_pkts, interpret=interpret)
+        # fold, elementwise per packet, so the numerics match the eager
+        # drain on any topology
+        def weigh(ops, rows):
+            return norm_clip_weights(ops.weights, rows, tau=clip_tau,
+                                     scales=ops.scales)
+    leaf = functools.partial(
+        _fold_leaf, exact=(mode == "exact"), use_pallas=use_pallas,
+        block_slots=block_slots, block_pkts=block_pkts,
+        interpret=interpret, weigh=weigh)
+    ops = Leaves(sched_idx, sched_w, sched_pk, sched_src, sched_scales,
+                 None)
+    acc, cnt = fold_leaves(leaf, ops, acc, cnt, axes=axes, mesh=mesh)
     total, counts = acc[:S], cnt[:S, 0]
-    avg = total / jnp.maximum(counts, 1e-12)[:, None]
-    avg = jnp.where(counts[:, None] > 0, avg, 0.0)
-    agg_flat = depacketize(avg, n_params)
-    have = expand_packet_mask(counts > 0, payload, n_params)
-    new_global = jnp.where(have, agg_flat, prev_global)
-    new_flats = None
-    if client_flats is not None:
-        down_elem = expand_packet_mask(down_mask, payload, n_params)
-        new_flats = jnp.where(down_elem > 0, new_global[None, :],
-                              client_flats)
-        if mix_alpha > 0:
-            new_flats = mix_alpha * client_flats + (1 - mix_alpha) * new_flats
+    new_global, new_flats = _publish(
+        _mean(total, counts), counts > 0, prev_global, client_flats,
+        down_mask, payload=payload, n_params=n_params, mix_alpha=mix_alpha)
     return total, counts, new_global, new_flats
 
 
@@ -755,7 +776,7 @@ def _round_device(total, counts, sched_idx, sched_w, sched_pk, sched_src,
                                     "n_clients", "use_pallas",
                                     "block_slots", "block_pkts",
                                     "mix_alpha", "interpret", "median",
-                                    "beta", "shards", "hosts", "mesh"))
+                                    "beta", "axes", "mesh"))
 def _robust_round_device(sched_idx, sched_w, sched_pk, sched_src,
                          sched_scales, prev_global, client_flats, down_mask,
                          *,
@@ -763,8 +784,7 @@ def _robust_round_device(sched_idx, sched_w, sched_pk, sched_src,
                          n_clients: int, use_pallas: bool,
                          block_slots: int, block_pkts: int,
                          mix_alpha: float, interpret: bool, median: bool,
-                         beta: float, shards: int = 1, hosts: int = 1,
-                         mesh=None):
+                         beta: float, axes: tuple = (), mesh=None):
     """Robust table round (trimmed-mean / median, DESIGN.md §11) as one
     compiled dataflow.
 
@@ -775,11 +795,13 @@ def _robust_round_device(sched_idx, sched_w, sched_pk, sched_src,
     exactly once (dedup upstream), so ``0 + 1.0·row`` reproduces the
     eager engine's direct table assignment bitwise (q8 rows dequantize
     in-body as ever).  The fold always runs exact — approx mode's
-    last-writer-wins window cannot race rows that never collide.  The
+    last-writer-wins window cannot race rows that never collide — and
+    on a mesh each row lives on one leaf, so the psums add it to zeros:
+    bitwise at any factorization on ANY payloads (DESIGN.md §12).  The
     reshaped table feeds the fused rank-select finalize; the per-slot
     contributor count ``m`` replaces the mean path's ``counts`` (same
-    fallback semantics), and the TX downlink fuses in as usual.
-    ``sched_src`` is read as in ``_round_device``.
+    fallback semantics), and the TX downlink fuses in as usual.  The
+    schedule arrays and ``axes`` are read as in ``_round_device``.
 
     No donation: the carried ``(S, W)`` accumulators are the wrong
     shape for the table; the returned ``total`` is the table's per-slot
@@ -787,43 +809,18 @@ def _robust_round_device(sched_idx, sched_w, sched_pk, sched_src,
     """
     S, K = n_slots, n_clients
     SK = S * K
-    if sched_src is not None:
-        sched_pk = _take_rows(sched_pk, sched_src)
-    # jnp single-shard path: the unique combined indices let the whole
-    # schedule fold as ONE flat scatter (packet_table_scatter) instead
-    # of the batch scan — the scan's per-batch (S·K, B) one-hot routing
-    # is quadratic in the table height.  +1 dustbin row for the idx=-1
-    # padding; pallas keeps the blocked grid (its production body).
-    flat_fold = shards == 1 and hosts == 1 and not use_pallas
+    # +1 dustbin row for the flat scatter's idx=-1 padding on the jnp
+    # body; pallas pads the table to whole slot blocks
     pad = (-SK) % block_slots if use_pallas else 1
-    acc = jnp.zeros((SK + pad, payload), jnp.float32)
     cnt = jnp.zeros((SK + pad, 1), jnp.float32)
-    if flat_fold:
-        acc, cnt = packet_table_scatter(sched_idx, sched_w, sched_pk,
-                                        acc, cnt,
-                                        sched_scales=sched_scales)
-    elif hosts > 1:
-        # each (slot, client) row lives on exactly one host (ownership)
-        # and is written exactly once (dedup), so the host-level psum
-        # adds its 0+1.0·row to H-1 zeros: bitwise at any host count on
-        # ANY payloads, not just integer ones (DESIGN.md §12)
-        acc, cnt = packet_scatter_accum_hier(
-            sched_idx, sched_w, sched_pk, acc, cnt,
-            sched_scales=sched_scales, mesh=mesh, exact=True,
-            use_pallas=use_pallas, block_slots=block_slots,
-            block_pkts=block_pkts, interpret=interpret)
-    elif shards > 1:
-        acc, cnt = packet_scatter_accum_sharded(
-            sched_idx, sched_w, sched_pk, acc, cnt,
-            sched_scales=sched_scales, mesh=mesh, exact=True,
-            use_pallas=use_pallas, block_slots=block_slots,
-            block_pkts=block_pkts, interpret=interpret)
-    else:
-        acc, cnt = packet_scatter_accum_scan(
-            sched_idx, sched_w, sched_pk, acc, cnt,
-            sched_scales=sched_scales, exact=True,
-            use_pallas=use_pallas, block_slots=block_slots,
-            block_pkts=block_pkts, interpret=interpret)
+    acc = jnp.zeros((SK + pad, payload), jnp.float32)
+    leaf = functools.partial(
+        _fold_leaf, exact=True, use_pallas=use_pallas,
+        block_slots=block_slots, block_pkts=block_pkts,
+        interpret=interpret, table=True)
+    ops = Leaves(sched_idx, sched_w, sched_pk, sched_src, sched_scales,
+                 None)
+    acc, cnt = fold_leaves(leaf, ops, acc, cnt, axes=axes, mesh=mesh)
     table = acc[:SK].reshape(S, K, payload)
     pres = cnt[:SK, 0].reshape(S, K)
     if use_pallas:
@@ -837,16 +834,9 @@ def _robust_round_device(sched_idx, sched_w, sched_pk, sched_src,
     else:
         agg, m = robust_finalize_jnp(table, pres, median=median, beta=beta)
     total = jnp.sum(table, axis=1)                        # (S, W)
-    agg_flat = depacketize(agg, n_params)
-    have = expand_packet_mask(m > 0, payload, n_params)
-    new_global = jnp.where(have, agg_flat, prev_global)
-    new_flats = None
-    if client_flats is not None:
-        down_elem = expand_packet_mask(down_mask, payload, n_params)
-        new_flats = jnp.where(down_elem > 0, new_global[None, :],
-                              client_flats)
-        if mix_alpha > 0:
-            new_flats = mix_alpha * client_flats + (1 - mix_alpha) * new_flats
+    new_global, new_flats = _publish(
+        agg, m > 0, prev_global, client_flats, down_mask, payload=payload,
+        n_params=n_params, mix_alpha=mix_alpha)
     return total, m, new_global, new_flats
 
 
@@ -856,7 +846,7 @@ def _combined_table_sched(sched: DrainSchedule,
     index -> combined ``slot·K + client`` index, per-arrival FedAvg
     weight -> presence weight 1.0 (rank statistics are unweighted).
     Batch composition — and hence shard ownership — is untouched, so
-    ``shard_schedule`` applies downstream unchanged."""
+    ``leaf_layout``'s shard split applies downstream unchanged."""
     assert sched.clients is not None, \
         "robust table modes need a client-tracked schedule"
     valid = sched.idx >= 0
@@ -875,17 +865,11 @@ def dispatch_round(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     new_flats|None).  ``total``/``counts`` are donated — callers pass
     buffers they own and adopt the returned ones.
 
-    ``cfg.shards > 1`` demuxes the schedule per shard and routes the
-    scan through the sharded partial-sum path: over a real ``'worker'``
-    mesh when the platform has enough devices
-    (``runtime.sharding.worker_mesh``), else — on the CPU only — a
-    bitwise single-device emulation.  ``cfg.hosts > 1`` first partitions
-    the arrivals by client-range ownership
-    (``partition_schedule_by_host``), re-demuxes each host's stream with
-    its own rings, shard-splits within each host, and routes through the
-    two-level psum fold over the 2-D ``('host', 'worker')`` mesh
-    (``runtime.sharding.host_worker_mesh``) — or its bitwise
-    nested-vmap emulation (DESIGN.md §12).
+    ``cfg.shards > 1`` and ``cfg.hosts > 1`` lay the schedule out per
+    leaf (``leaf_layout``) and fold it over a real ``'worker'`` or
+    ``('host', 'worker')`` mesh when the platform has enough devices
+    (``runtime.sharding``), else — on the CPU only — over a bitwise
+    single-device emulation (DESIGN.md §7, §12).
     """
     fn, args, kwargs = _round_call(cfg, sched, total, counts, prev_global,
                                    client_flats, down_mask, mix_alpha)
@@ -911,52 +895,25 @@ def _round_call(cfg: EngineConfig, sched: DrainSchedule, total, counts,
     if cfg.mode not in ("exact", "approx"):
         raise ValueError(cfg.mode)
     robust_table = cfg.agg_mode in TABLE_MODES
-    mesh = src = None
-    if cfg.hosts > 1:
-        # partition BEFORE the robust index rewrite (ownership keys on
-        # the original client column) and before the shard split (ring
-        # ownership applies within each host)
-        per_host = partition_schedule_by_host(
-            sched, cfg.hosts, cfg.n_clients, n_workers=cfg.n_workers,
-            ring_capacity=cfg.ring_capacity, ring_assign=cfg.ring_assign)
-        if robust_table:
-            per_host = [_combined_table_sched(s, cfg.n_clients)
-                        for s in per_host]
-        idx, w, pk, sc, _ = _stack_host_shards(
-            [shard_schedule(s, cfg.shards) for s in per_host])
-        ctx = host_ctx(cfg.hosts, cfg.shards)
-        mesh = None if ctx is None else ctx.mesh
-    else:
-        if robust_table:
-            sched = _combined_table_sched(sched, cfg.n_clients)
-        if cfg.shards > 1:
-            # shards regroup batches on the host, so they ship payloads
-            # expanded there; one chip ships the compact rows and src
-            idx, w, pk, sc, _ = shard_schedule(sched, cfg.shards)
-            ctx = worker_ctx(cfg.shards)
-            mesh = None if ctx is None else ctx.mesh
-        else:
-            idx, w, pk, src, sc = (sched.idx, sched.weights,
-                                   sched.payloads, sched.src, sched.scales)
+    axes, _, mesh = _topology(cfg)
+    idx, w, pk, src, sc, _ = (None if a is None else a[0]
+                              for a in leaf_layout(cfg, [sched]))
+
     def opt(a):
         return None if a is None else jnp.asarray(a)
 
     with _span("fl.h2d"):
-        if robust_table:
-            args = (jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk),
-                    opt(src), opt(sc), jnp.asarray(prev_global),
-                    opt(client_flats), opt(down_mask))
-        else:
-            args = (jnp.asarray(total, jnp.float32),
-                    jnp.asarray(counts, jnp.float32), jnp.asarray(idx),
-                    jnp.asarray(w), jnp.asarray(pk), opt(src), opt(sc),
-                    jnp.asarray(prev_global), opt(client_flats),
-                    opt(down_mask))
+        # the table modes' round builds its own accumulator
+        args = () if robust_table else (jnp.asarray(total, jnp.float32),
+                                        jnp.asarray(counts, jnp.float32))
+        args += (jnp.asarray(idx), jnp.asarray(w), jnp.asarray(pk),
+                 jnp.asarray(src), opt(sc), jnp.asarray(prev_global),
+                 opt(client_flats), opt(down_mask))
     common = dict(payload=cfg.payload, n_params=cfg.n_params,
                   use_pallas=_use_pallas(cfg), block_slots=8,
                   block_pkts=min(BLOCK_PKTS, idx.shape[-1]),
                   mix_alpha=float(mix_alpha), interpret=_interpret(),
-                  shards=cfg.shards, hosts=cfg.hosts, mesh=mesh)
+                  axes=axes, mesh=mesh)
     if robust_table:
         return _robust_round_device, args, dict(
             common, n_slots=cfg.n_slots, n_clients=cfg.n_clients,
@@ -1053,28 +1010,26 @@ def run_compiled_rounds(cfg: EngineConfig, rounds: Iterable,
 
 @dataclasses.dataclass
 class AsyncSchedule:
-    """Stacked per-window drain schedule for one async demux call.
+    """The per-window drain schedules of one async demux call.
 
     Window ``w`` holds the packets of the updates folded between emit
     boundaries ``w-1`` and ``w``; every window is independently
     ring-demuxed (``build_drain_schedule`` — rings and the rr pointer
-    reset at each emit, exactly like the eager twin) and the per-window
-    schedules are padded to a common row count ``R`` and stacked so the
-    whole call scans as one ``lax.scan`` over windows.  ``emit[w]``
-    marks windows that close a full buffer (the divide fires and the
-    accumulator resets); a trailing non-emit window carries the
+    reset at each emit, exactly like the eager twin), and
+    ``leaf_layout`` pads the windows to a common row count and stacks
+    them, so the whole call scans as one ``lax.scan`` over windows.
+    ``emit[w]`` marks windows that close a full buffer (the divide fires
+    and the accumulator resets); a trailing non-emit window carries the
     residual (< B) folds into the returned ``AsyncState``.
     """
-    idx: np.ndarray         # (n_windows, R, B) int32 slot rows
-    weights: np.ndarray     # (n_windows, R, B) f32 base FedAvg weights
-    staleness: np.ndarray   # (n_windows, R, B) f32 update age at fold
-    payloads: np.ndarray    # (n_windows, R, B, W) f32 | int8 (q8 wire)
+    scheds: List[DrainSchedule]
     emit: np.ndarray        # (n_windows,) bool — divide + reset fires
-    n_windows: int
     n_emits: int
     pending_after: int      # updates folded past the last emit
-    scales: Optional[np.ndarray] = None    # (n_windows, R, B) f32 (q8)
-    scheds: List[DrainSchedule] = dataclasses.field(default_factory=list)
+
+    @property
+    def n_windows(self) -> int:
+        return len(self.scheds)
 
 
 def demux_events_async(cfg: EngineConfig, events: Iterable,
@@ -1206,34 +1161,8 @@ def demux_events_async(cfg: EngineConfig, events: Iterable,
 
     scheds = [_window_sched(w) for w in windows]
     stats.batches_drained = sum(s.n_batches for s in scheds)
-    n_windows = len(scheds)
-    if n_windows == 0:
-        asched = AsyncSchedule(
-            np.zeros((0, 1, 1), np.int32), np.zeros((0, 1, 1), np.float32),
-            np.zeros((0, 1, 1), np.float32),
-            np.zeros((0, 1, 1, cfg.payload), np.float32),
-            np.zeros((0,), bool), 0, 0, pending, None, [])
-        return asched, stats, updates
-    B = scheds[0].idx.shape[1]
-    W = scheds[0].payloads.shape[1]
-    R = max(s.idx.shape[0] for s in scheds)
-    idx = np.full((n_windows, R, B), -1, np.int32)
-    w_all = np.zeros((n_windows, R, B), np.float32)
-    st_all = np.zeros((n_windows, R, B), np.float32)
-    pk_all = np.zeros((n_windows, R, B, W), scheds[0].payloads.dtype)
-    sc_all = (np.zeros((n_windows, R, B), np.float32) if homogeneous_q8
-              else None)
-    for i, s in enumerate(scheds):
-        r = s.idx.shape[0]
-        idx[i, :r] = s.idx
-        w_all[i, :r] = s.weights
-        st_all[i, :r] = s.staleness
-        pk_all[i, :r] = expand_payloads(s)
-        if sc_all is not None:
-            sc_all[i, :r] = s.scales
-    asched = AsyncSchedule(idx, w_all, st_all, pk_all,
-                           np.asarray(emit_flags, bool), n_windows, emits,
-                           pending, sc_all, scheds)
+    asched = AsyncSchedule(scheds, np.asarray(emit_flags, bool), emits,
+                           pending)
     return asched, stats, updates
 
 
@@ -1243,23 +1172,23 @@ def demux_events_async(cfg: EngineConfig, events: Iterable,
                                     "block_pkts", "interpret",
                                     "stale_mode", "stale_alpha",
                                     "norm_clip", "agg_clip", "clip_tau",
-                                    "shards", "hosts", "mesh"),
+                                    "axes", "mesh"),
                    donate_argnums=(0, 1))
-def _async_device(total, counts, g, sched_idx, sched_w, sched_st, sched_pk,
-                  sched_scales, emit, *, mode: str, payload: int,
-                  n_params: int, use_pallas: bool, block_slots: int,
-                  block_pkts: int, interpret: bool, stale_mode: str,
-                  stale_alpha: float, norm_clip: float,
+def _async_device(total, counts, g, ops: Leaves, emit, *, mode: str,
+                  payload: int, n_params: int, use_pallas: bool,
+                  block_slots: int, block_pkts: int, interpret: bool,
+                  stale_mode: str, stale_alpha: float, norm_clip: float,
                   agg_clip: bool = False, clip_tau: float = 1.0,
-                  shards: int = 1, hosts: int = 1, mesh=None):
+                  axes: tuple = (), mesh=None):
     """One jitted dispatch for a whole async demux call (DESIGN.md §10).
 
-    ``lax.scan`` over emit windows with the donated ``(total, counts)``
+    ``lax.scan`` over emit windows (``ops`` leads with the window axis,
+    then the leaf axes ``axes``) with the donated ``(total, counts)``
     accumulators and the live global carried in place.  Each window
     step: the staleness weighting (``staleness_weights`` — applied
     in-body, so the q8 wire's norm screening sees the dequantized rows
     without ever materializing them) rescales the window's base
-    weights, the window's drain rows fold through the same scan body as
+    weights, the window's drain rows fold through the same leaf fold as
     a synchronous round, and — where ``emit`` is set — the END divide +
     per-slot fallback publishes a new global and zeroes the
     accumulators for the next buffer.  Non-emit windows (the residual
@@ -1272,55 +1201,38 @@ def _async_device(total, counts, g, sched_idx, sched_w, sched_st, sched_pk,
     if pad:
         acc = jnp.pad(acc, ((0, pad), (0, 0)))
         cnt = jnp.pad(cnt, ((0, pad), (0, 0)))
-    q8 = sched_scales is not None
 
-    def step(carry, xs):
-        acc, cnt, g = carry
-        if q8:
-            widx, ww, wst, wsc, wpk, em = xs
-        else:
-            widx, ww, wst, wpk, em = xs
-            wsc = None
-        eff = staleness_weights(ww, wst, rows=wpk, scales=wsc,
-                                mode=stale_mode, alpha=stale_alpha,
-                                norm_clip=norm_clip)
+    def weigh(ops, rows):
+        eff = staleness_weights(ops.weights, ops.staleness, rows=rows,
+                                scales=ops.scales, mode=stale_mode,
+                                alpha=stale_alpha, norm_clip=norm_clip)
         if agg_clip:
             # agg_mode="norm_clip" composes *after* the staleness
             # weighting, matching the eager _fold_window (§11)
-            eff = norm_clip_weights(eff, wpk, tau=clip_tau, scales=wsc)
-        if hosts > 1:
-            acc, cnt = packet_scatter_accum_hier(
-                widx, eff, wpk, acc, cnt, sched_scales=wsc, mesh=mesh,
-                exact=(mode == "exact"), use_pallas=use_pallas,
-                block_slots=block_slots, block_pkts=block_pkts,
-                interpret=interpret)
-        elif shards > 1:
-            acc, cnt = packet_scatter_accum_sharded(
-                widx, eff, wpk, acc, cnt, sched_scales=wsc, mesh=mesh,
-                exact=(mode == "exact"), use_pallas=use_pallas,
-                block_slots=block_slots, block_pkts=block_pkts,
-                interpret=interpret)
-        else:
-            acc, cnt = packet_scatter_accum_scan(
-                widx, eff, wpk, acc, cnt, sched_scales=wsc,
-                exact=(mode == "exact"), use_pallas=use_pallas,
-                block_slots=block_slots, block_pkts=block_pkts,
-                interpret=interpret)
+            eff = norm_clip_weights(eff, rows, tau=clip_tau,
+                                    scales=ops.scales)
+        return eff
+
+    leaf = functools.partial(
+        _fold_leaf, exact=(mode == "exact"), use_pallas=use_pallas,
+        block_slots=block_slots, block_pkts=block_pkts,
+        interpret=interpret, weigh=weigh)
+
+    def step(carry, xs):
+        acc, cnt, g = carry
+        window, em = xs
+        acc, cnt = fold_leaves(leaf, window, acc, cnt, axes=axes, mesh=mesh)
         counts_live = cnt[:S, 0]
-        # the emit divide — the exact op sequence of the synchronous END
-        avg = acc[:S] / jnp.maximum(counts_live, 1e-12)[:, None]
-        avg = jnp.where(counts_live[:, None] > 0, avg, 0.0)
-        agg_flat = depacketize(avg, n_params)
-        have = expand_packet_mask(counts_live > 0, payload, n_params)
-        cand = jnp.where(have, agg_flat, g)
+        # the emit: the exact op sequence of the synchronous END
+        cand, _ = _publish(_mean(acc[:S], counts_live), counts_live > 0, g,
+                           None, None, payload=payload, n_params=n_params,
+                           mix_alpha=0.0)
         new_g = jnp.where(em, cand, g)
         acc = jnp.where(em, jnp.zeros_like(acc), acc)
         cnt = jnp.where(em, jnp.zeros_like(cnt), cnt)
         return (acc, cnt, new_g), (new_g, counts_live)
 
-    xs = ((sched_idx, sched_w, sched_st, sched_scales, sched_pk, emit)
-          if q8 else (sched_idx, sched_w, sched_st, sched_pk, emit))
-    (acc, cnt, g), (gs, cs) = jax.lax.scan(step, (acc, cnt, g), xs)
+    (acc, cnt, g), (gs, cs) = jax.lax.scan(step, (acc, cnt, g), (ops, emit))
     return acc[:S], cnt[:S, 0], g, gs, cs
 
 
@@ -1329,84 +1241,25 @@ def dispatch_async(cfg: EngineConfig, asched: AsyncSchedule, total, counts,
     """Dispatch one async demux call -> (total', counts', final_global,
     per-window globals (n_windows, P), per-window counts (n_windows, N)).
 
-    ``total``/``counts`` are donated.  ``cfg.shards > 1`` demuxes every
-    window's schedule per shard (ring ownership, ``shard_schedule``)
-    and routes each window through the sharded partial-sum fold — over
-    the ``'worker'`` mesh when the platform has the devices, else the
-    bitwise vmap emulation.  ``cfg.hosts > 1`` additionally partitions
-    every window's arrivals by client-range ownership first
-    (``partition_schedule_by_host``) and routes through the two-level
-    fold over the (host, worker) mesh (DESIGN.md §12).
+    ``total``/``counts`` are donated.  Every window is laid out per leaf
+    as a synchronous round is (``leaf_layout``: host partition, then
+    shard split) and folds over the same mesh or its bitwise emulation
+    (DESIGN.md §7, §12).
     """
-    idx, w, st, pk, sc = (asched.idx, asched.weights, asched.staleness,
-                          asched.payloads, asched.scales)
-    mesh = None
-    if cfg.hosts > 1:
-        per_win = []
-        for s in asched.scheds:
-            ph = partition_schedule_by_host(
-                s, cfg.hosts, cfg.n_clients, n_workers=cfg.n_workers,
-                ring_capacity=cfg.ring_capacity,
-                ring_assign=cfg.ring_assign)
-            per_win.append(_stack_host_shards(
-                [shard_schedule(p, cfg.shards) for p in ph]))
-        R = max(p[0].shape[2] for p in per_win)
-        nW, H, nS = asched.n_windows, cfg.hosts, cfg.shards
-        B = asched.idx.shape[2]
-        W = asched.payloads.shape[3]
-        idx = np.full((nW, H, nS, R, B), -1, np.int32)
-        w = np.zeros((nW, H, nS, R, B), np.float32)
-        st = np.zeros((nW, H, nS, R, B), np.float32)
-        pk = np.zeros((nW, H, nS, R, B, W), asched.payloads.dtype)
-        sc = (None if asched.scales is None
-              else np.zeros((nW, H, nS, R, B), np.float32))
-        for i, (pi, pw, ppk, psc, pst) in enumerate(per_win):
-            r = pi.shape[2]
-            idx[i, :, :, :r] = pi
-            w[i, :, :, :r] = pw
-            st[i, :, :, :r] = pst
-            pk[i, :, :, :r] = ppk
-            if sc is not None:
-                sc[i, :, :, :r] = psc
-        ctx = host_ctx(cfg.hosts, cfg.shards)
-        mesh = None if ctx is None else ctx.mesh
-    elif cfg.shards > 1:
-        per_win = [shard_schedule(s, cfg.shards) for s in asched.scheds]
-        R = max(p[0].shape[1] for p in per_win)
-        nW, nS = asched.n_windows, cfg.shards
-        B = asched.idx.shape[2]
-        W = asched.payloads.shape[3]
-        idx = np.full((nW, nS, R, B), -1, np.int32)
-        w = np.zeros((nW, nS, R, B), np.float32)
-        st = np.zeros((nW, nS, R, B), np.float32)
-        pk = np.zeros((nW, nS, R, B, W), asched.payloads.dtype)
-        sc = (None if asched.scales is None
-              else np.zeros((nW, nS, R, B), np.float32))
-        for i, (pi, pw, ppk, psc, pst) in enumerate(per_win):
-            r = pi.shape[1]
-            idx[i, :, :r] = pi
-            w[i, :, :r] = pw
-            st[i, :, :r] = pst
-            pk[i, :, :r] = ppk
-            if sc is not None:
-                sc[i, :, :r] = psc
-        ctx = worker_ctx(cfg.shards)
-        mesh = None if ctx is None else ctx.mesh
+    axes, _, mesh = _topology(cfg)
+    ops = leaf_layout(cfg, asched.scheds)
     return _async_device(
         jnp.asarray(total, jnp.float32), jnp.asarray(counts, jnp.float32),
         jnp.asarray(prev_global, jnp.float32),
-        jnp.asarray(idx), jnp.asarray(w), jnp.asarray(st), jnp.asarray(pk),
-        None if sc is None else jnp.asarray(sc),
-        jnp.asarray(asched.emit),
+        jax.tree.map(jnp.asarray, ops), jnp.asarray(asched.emit),
         mode=cfg.mode, payload=cfg.payload, n_params=cfg.n_params,
         use_pallas=_use_pallas(cfg), block_slots=8,
-        block_pkts=min(BLOCK_PKTS, idx.shape[-1]),
+        block_pkts=min(BLOCK_PKTS, ops.idx.shape[-1]),
         interpret=_interpret(), stale_mode=cfg.staleness_mode,
         stale_alpha=float(cfg.staleness_alpha),
         norm_clip=float(cfg.norm_clip),
         agg_clip=(cfg.agg_mode == "norm_clip"),
-        clip_tau=float(cfg.clip_tau), shards=cfg.shards, hosts=cfg.hosts,
-        mesh=mesh)
+        clip_tau=float(cfg.clip_tau), axes=axes, mesh=mesh)
 
 
 def run_compiled_async(cfg: EngineConfig, events: Iterable, prev_global,

@@ -50,6 +50,8 @@ backends, where the interpreted grid would unroll per batch), and
 schedule through either body as one ``lax.scan`` with the accumulator
 carried in place.  ``fold_block_visits`` counts, for a whole schedule,
 the slot blocks the hit-block grid visits against the dense sweep's.
+``fold_leaves`` runs one leaf's fold on one chip, or over the shards or
+hosts × shards of a mesh with one psum per level (DESIGN.md §7, §12).
 """
 from __future__ import annotations
 
@@ -751,197 +753,81 @@ def packet_scatter_accum_scan(sched_idx: jnp.ndarray, sched_w: jnp.ndarray,
     return acc, counts
 
 
-def combine_partials(acc_parts: jnp.ndarray, cnt_parts: jnp.ndarray,
-                     axis_name=None, axis=0):
-    """Merge per-shard partial sums (the paper's per-core combine, §3.2).
+def combine_partials(acc_parts: jnp.ndarray, cnt_parts: jnp.ndarray, *,
+                     levels: tuple = (), axes: tuple = ()):
+    """Merge per-leaf partial sums (the paper's per-core combine, §3.2).
 
-    Inside ``shard_map`` the partials live one-per-device and the merge
-    is a single ``psum`` per mesh level over ``axis_name`` — a string
-    for the 1-D worker mesh, or a sequence (innermost level first, e.g.
-    ``('worker', 'host')``, DESIGN.md §12) for the hierarchical mesh.
-    In the single-device emulation the partials carry leading shard
-    axes and the merge is a plain sum over ``axis`` (an int or a
-    sequence of ints, summed innermost/highest axis first to mirror the
-    psum order).  Every ordering adds exactly one partial per leaf, so
-    for payloads whose sums are exactly representable in f32
-    (integer-valued test streams) all paths are bitwise identical.
+    Inside ``shard_map`` the partials live one per device and the merge
+    is one ``psum`` per mesh level in ``levels``, innermost first (e.g.
+    ``('worker', 'host')``, DESIGN.md §12).  In the single-device
+    emulation the partials carry leading leaf axes and the merge is a
+    plain sum over ``axes``, highest first to mirror the psum order.
+    Every ordering adds exactly one partial per leaf, so for payloads
+    whose sums are exactly representable in f32 (integer-valued test
+    streams) all paths are bitwise identical.
     """
-    if axis_name is not None:
-        names = ((axis_name,) if isinstance(axis_name, str)
-                 else tuple(axis_name))
-        for name in names:
-            acc_parts = jax.lax.psum(acc_parts, name)
-            cnt_parts = jax.lax.psum(cnt_parts, name)
-        return acc_parts, cnt_parts
-    axes = (axis,) if isinstance(axis, int) else tuple(axis)
+    for name in levels:
+        acc_parts = jax.lax.psum(acc_parts, name)
+        cnt_parts = jax.lax.psum(cnt_parts, name)
     for ax in sorted(axes, reverse=True):
         acc_parts = jnp.sum(acc_parts, axis=ax)
         cnt_parts = jnp.sum(cnt_parts, axis=ax)
     return acc_parts, cnt_parts
 
 
-def packet_scatter_accum_sharded(sched_idx: jnp.ndarray,
-                                 sched_w: jnp.ndarray,
-                                 sched_pk: jnp.ndarray, acc: jnp.ndarray,
-                                 counts: jnp.ndarray, *,
-                                 sched_scales: jnp.ndarray | None = None,
-                                 mesh=None, axis_name: str = "worker",
-                                 exact: bool = True,
-                                 use_pallas: bool = False,
-                                 block_slots: int = 8,
-                                 block_pkts: int = BLOCK_PKTS,
-                                 interpret: bool = False):
-    """Sharded round scan: per-shard partial sums + one combine at END.
+def fold_leaves(leaf, operands, acc: jnp.ndarray, counts: jnp.ndarray, *,
+                axes: tuple = (), mesh=None):
+    """Fold a round's leaves into ``(acc, counts)``: the one seam where
+    the topology meets the device (DESIGN.md §7, §12).
 
-    sched_idx/sched_w (n_shards, R, B) and sched_pk (n_shards, R, B, W)
-    carry the drain schedule demuxed per shard
-    (``engine_compiled.shard_schedule``): shard s owns the drain batches
-    of the worker rings mapped to it, padded to a common row count R
-    with inert rows.  Each shard folds its slice through the unsharded
-    scan body (``packet_scatter_accum_scan``) into **zero-initialized
-    shard-local (total, counts) partials** — the DPU's per-core
-    accumulators — and the partials are merged by ``combine_partials``:
-    a ``psum`` over the ``'worker'`` mesh axis when ``mesh`` is given
-    (real devices, via ``shard_map``), else a sum over the leading shard
-    axis (vmap emulation, any device count).  The incoming ``acc`` /
-    ``counts`` are added after the combine.
+    A leaf is the single chip, a worker shard, or a (host, shard) pair;
+    ``operands`` is a pytree whose arrays carry one leading axis per
+    name in ``axes`` (none, ``('worker',)`` or ``('host', 'worker')``),
+    and ``leaf(ops, acc, counts) -> (acc, counts)`` folds one leaf's
+    slice.  With no axes the leaf folds straight into the incoming
+    accumulators.  Otherwise every leaf folds into zero-initialized
+    leaf-local partials — the DPU's per-core accumulators — that
+    ``combine_partials`` merges with one psum per mesh level, innermost
+    first (worker within a host, then host across hosts), over ``mesh``
+    via ``shard_map``; without a mesh, nested vmaps emulate the leaves
+    on one device and sum their axes in the same order.  The incoming
+    ``acc`` / ``counts`` are added after the combine.
 
-    Exactness: both modes' per-batch contributions are additive — exact
-    adds every weighted arrival, approx adds exactly one last-writer
-    contribution per (slot, drained batch) — so regrouping batches by
-    shard changes only f32 summation order.  On integer-valued payloads
-    the result is bitwise identical to the unsharded scan over the same
-    schedule, in both modes (tests/test_engine_sharded.py).
+    Exactness: the leaves only regroup additive per-batch contributions
+    (exact adds every weighted arrival, approx one last-writer
+    contribution per (slot, drained batch)), so on payloads whose sums
+    are exactly representable in f32 every factorization is bitwise the
+    single leaf (tests/test_engine_sharded.py, test_engine_hier.py).
     """
-    body = functools.partial(
-        packet_scatter_accum_scan, exact=exact, use_pallas=use_pallas,
-        block_slots=block_slots, block_pkts=block_pkts, interpret=interpret)
+    if not axes:
+        return leaf(operands, acc, counts)
     zero_acc = jnp.zeros_like(acc)
     zero_cnt = jnp.zeros_like(counts)
-    q8 = sched_scales is not None
-    # check_vma=False: the scan carries start from the replicated zeros
-    # and leave varying per shard, which shard_map's varying-axis typing
-    # rejects as a carry type change; the psum makes the outputs
-    # replicated again, as out_specs=P() says
+
+    def part(ops):
+        return leaf(ops, zero_acc, zero_cnt)
+
+    levels = axes[::-1]                       # innermost level first
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        spec = P(axis_name)
-        if q8:
-            def shard_fn(bidx, bw, bsc, bpk):
-                # leading shard axis is size 1 on each device
-                a, c = body(bidx[0], bw[0], bpk[0], zero_acc, zero_cnt,
-                            sched_scales=bsc[0])
-                return combine_partials(a, c, axis_name=axis_name)
+        def shard_fn(ops):
+            # every leading mesh axis is size 1 on each device
+            a, c = part(jax.tree.map(lambda x: x[(0,) * len(axes)], ops))
+            return combine_partials(a, c, levels=levels)
 
-            a, c = jax.shard_map(
-                shard_fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                out_specs=(P(), P()), check_vma=False,
-            )(sched_idx, sched_w, sched_scales, sched_pk)
-        else:
-            def shard_fn(bidx, bw, bpk):
-                # leading shard axis is size 1 on each device
-                a, c = body(bidx[0], bw[0], bpk[0], zero_acc, zero_cnt)
-                return combine_partials(a, c, axis_name=axis_name)
-
-            a, c = jax.shard_map(
-                shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=(P(), P()), check_vma=False,
-            )(sched_idx, sched_w, sched_pk)
-    elif q8:
-        a_parts, c_parts = jax.vmap(
-            lambda bidx, bw, bsc, bpk: body(bidx, bw, bpk, zero_acc,
-                                            zero_cnt, sched_scales=bsc)
-        )(sched_idx, sched_w, sched_scales, sched_pk)
-        a, c = combine_partials(a_parts, c_parts)
+        # check_vma=False: the scan carries start from the replicated
+        # zeros and leave varying per leaf, which shard_map's
+        # varying-axis typing rejects as a carry type change; the psum
+        # makes the outputs replicated again, as out_specs=P() says
+        a, c = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(*axes),),
+                             out_specs=(P(), P()), check_vma=False,
+                             )(operands)
     else:
-        a_parts, c_parts = jax.vmap(
-            lambda bidx, bw, bpk: body(bidx, bw, bpk, zero_acc, zero_cnt)
-        )(sched_idx, sched_w, sched_pk)
-        a, c = combine_partials(a_parts, c_parts)
-    return acc + a, counts + c
-
-
-def packet_scatter_accum_hier(sched_idx: jnp.ndarray,
-                              sched_w: jnp.ndarray,
-                              sched_pk: jnp.ndarray, acc: jnp.ndarray,
-                              counts: jnp.ndarray, *,
-                              sched_scales: jnp.ndarray | None = None,
-                              mesh=None, host_axis: str = "host",
-                              worker_axis: str = "worker",
-                              exact: bool = True,
-                              use_pallas: bool = False,
-                              block_slots: int = 8,
-                              block_pkts: int = BLOCK_PKTS,
-                              interpret: bool = False):
-    """Hierarchical round scan over a 2-D (host, worker) mesh
-    (DESIGN.md §12).
-
-    sched_idx/sched_w (H, S, R, B) and sched_pk (H, S, R, B, W) carry
-    the drain schedule partitioned twice: by client-range ownership
-    across the H hosts (``engine_compiled.partition_schedule_by_host``)
-    and then by ring ownership across each host's S worker shards
-    (``engine_compiled.shard_schedule``), each (h, s) slice padded to a
-    common row count R with inert rows.  Each leaf folds its slice
-    through the unsharded scan body into zero-initialized leaf-local
-    ``(total, counts)`` partials, then ``combine_partials`` merges with
-    **one psum per mesh level** — worker-level within a host row first,
-    host-level across rows second — mirroring the paper's per-core
-    combine followed by the cross-machine combine.  Without a mesh the
-    emulation nests two vmaps and sums the two leading axes in the same
-    innermost-first order.
-
-    Exactness: both partitions only regroup the same additive per-batch
-    contributions, so on payloads whose sums are exactly representable
-    in f32 any (hosts, shards) factorization is bitwise identical to
-    the unsharded scan over the same arrivals
-    (tests/test_engine_hier.py).
-    """
-    body = functools.partial(
-        packet_scatter_accum_scan, exact=exact, use_pallas=use_pallas,
-        block_slots=block_slots, block_pkts=block_pkts, interpret=interpret)
-    zero_acc = jnp.zeros_like(acc)
-    zero_cnt = jnp.zeros_like(counts)
-    q8 = sched_scales is not None
-    # check_vma=False: the scan carries start from the replicated zeros
-    # and leave varying per shard, which shard_map's varying-axis typing
-    # rejects as a carry type change; the psum makes the outputs
-    # replicated again, as out_specs=P() says
-    if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-
-        spec = P(host_axis, worker_axis)
-        levels = (worker_axis, host_axis)     # innermost level first
-        if q8:
-            def shard_fn(bidx, bw, bsc, bpk):
-                # both leading mesh axes are size 1 on each device
-                a, c = body(bidx[0, 0], bw[0, 0], bpk[0, 0], zero_acc,
-                            zero_cnt, sched_scales=bsc[0, 0])
-                return combine_partials(a, c, axis_name=levels)
-
-            a, c = jax.shard_map(
-                shard_fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
-                out_specs=(P(), P()), check_vma=False,
-            )(sched_idx, sched_w, sched_scales, sched_pk)
-        else:
-            def shard_fn(bidx, bw, bpk):
-                a, c = body(bidx[0, 0], bw[0, 0], bpk[0, 0], zero_acc,
-                            zero_cnt)
-                return combine_partials(a, c, axis_name=levels)
-
-            a, c = jax.shard_map(
-                shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=(P(), P()), check_vma=False,
-            )(sched_idx, sched_w, sched_pk)
-    elif q8:
-        fold = jax.vmap(jax.vmap(
-            lambda bidx, bw, bsc, bpk: body(bidx, bw, bpk, zero_acc,
-                                            zero_cnt, sched_scales=bsc)))
-        a_parts, c_parts = fold(sched_idx, sched_w, sched_scales, sched_pk)
-        a, c = combine_partials(a_parts, c_parts, axis=(0, 1))
-    else:
-        fold = jax.vmap(jax.vmap(
-            lambda bidx, bw, bpk: body(bidx, bw, bpk, zero_acc, zero_cnt)))
-        a_parts, c_parts = fold(sched_idx, sched_w, sched_pk)
-        a, c = combine_partials(a_parts, c_parts, axis=(0, 1))
+        fold = part
+        for _ in axes:
+            fold = jax.vmap(fold)
+        a_parts, c_parts = fold(operands)
+        a, c = combine_partials(a_parts, c_parts,
+                                axes=tuple(range(len(axes))))
     return acc + a, counts + c

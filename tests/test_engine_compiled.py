@@ -246,24 +246,24 @@ def test_round_dispatch_donates_accumulators():
         ec._round_device,
         static_argnames=("mode", "payload", "n_params", "use_pallas",
                          "block_slots", "block_pkts", "mix_alpha",
-                         "interpret", "shards", "mesh"),
+                         "interpret", "axes", "mesh"),
         donate_argnums=(0, 1)).lower(
         total := jnp.zeros((cfg.n_slots, 32), jnp.float32),
         jnp.zeros((cfg.n_slots,), jnp.float32),
         jnp.asarray(sched.idx), jnp.asarray(sched.weights),
-        jnp.asarray(ec.expand_payloads(sched)), None, None, prev, None,
-        None,
+        jnp.asarray(sched.payloads), jnp.asarray(sched.src), None, prev,
+        None, None,
         mode="exact", payload=32, n_params=128, use_pallas=False,
         block_slots=8, block_pkts=128, mix_alpha=0.0, interpret=True,
-        shards=1, mesh=None)
+        axes=(), mesh=None)
     assert "tf.aliasing_output" in lowered.as_text()
 
 
 @pytest.mark.parametrize("assign", ["rr", "slot"])
 def test_shipped_rows_expand_to_the_dense_schedule(assign):
-    """A round ships only its accepted payload rows; expanded on the
-    host (``expand_payloads``) or on the device (the round's own
-    gather) they are bitwise the dense (n_rows, B, W) tensor of the
+    """A round ships only its accepted payload rows; expanded by the
+    plain numpy reference or on the device (the round's own gather)
+    they are bitwise the dense (n_rows, B, W) tensor of the
     eager batching, on a lossy, duplicated, reordered stream."""
     rng, _, _, pk = _round_inputs(3, int_valued=False)
     events, _ = make_uplink_stream(rng, pk, loss_rate=0.2, dup_rate=0.3)

@@ -266,10 +266,15 @@ def test_q8_schedule_stays_int8_end_to_end():
     for pkt, pay in ev:
         eng.rx(pkt, pay)
     assert all(eng._pend_q8)
-    # sharding carries the scale column alongside the weights
-    idx, w, pk, scs, _ = ec.shard_schedule(sched, 4)
-    assert pk.dtype == np.int8 and scs is not None
-    assert scs.shape == w.shape
+    # sharding carries the scale column alongside the weights, and each
+    # shard's rows stay int8
+    sharded = dataclasses.replace(cfg, shards=4)
+    lv = ec.leaf_layout(sharded, [sched])
+    assert lv.payloads.dtype == np.int8 and lv.scales is not None
+    assert lv.scales.shape == lv.weights.shape == (1, 4) + lv.idx.shape[-2:]
+    # every live cell keeps its packet's scale, on whichever shard
+    assert sorted(lv.scales[lv.idx >= 0].tolist()) == \
+        sorted(sched.scales[sched.idx >= 0].tolist())
     # and the f32 path still reports no scales
     ev_f, _ = make_uplink_stream(
         np.random.default_rng(13), jnp.asarray(_dequant_host(q, sc)),
@@ -277,7 +282,7 @@ def test_q8_schedule_stays_int8_end_to_end():
     sched_f, _, _ = ec.demux_events(cfg, ev_f)
     assert sched_f.payloads.dtype == np.float32
     assert sched_f.scales is None
-    assert ec.shard_schedule(sched_f, 4)[3] is None
+    assert ec.leaf_layout(sharded, [sched_f]).scales is None
 
 
 @pytest.mark.parametrize("wire", ["q8", "mixed"])

@@ -6,10 +6,11 @@ The load-bearing acceptance properties (ISSUE 4 + DESIGN.md §7):
    identical** to the unsharded compiled engine on integer-valued
    payloads — exact AND approx mode, both demux policies, lossy /
    duplicated / out-of-order streams.  Approx equality is the strong
-   check: it holds only because ``shard_schedule`` keeps every drain
+   check: it holds only because ``leaf_layout`` keeps every drain
    batch (the last-writer-wins race window) intact on one shard.
-2. The schedule demux is a partition: every live batch lands on the
-   shard owning its worker ring, padding is inert, nothing is dropped.
+2. The shard split is a partition: every live batch lands on the shard
+   owning its worker ring with its own payload rows, padding is inert,
+   nothing is dropped.
 3. Race accounting: per-shard approx-mode lost updates sum to the
    unsharded total (sharding splits the race ≈ 1/N per shard, it does
    not change it).
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _drain_reference import expand
 
 from repro.core import engine_compiled as ec
 from repro.core.packets import packetize
@@ -146,53 +148,76 @@ def _demuxed_schedule(seed=0, n_workers=5, ring_assign="rr", cap=7):
     return sched
 
 
+def _shard_leaves(sched, shards, *, n_workers=5, cap=7, payload=48):
+    """``leaf_layout``'s arrays for one round on ``shards`` shards, with
+    the leading shard axis kept even at one shard."""
+    cfg = EngineConfig(n_clients=6, n_params=480, payload=payload,
+                       ring_capacity=cap, n_workers=n_workers,
+                       shards=shards, compile=True)
+    lv = ec.leaf_layout(cfg, [sched])
+    return lv._replace(**{
+        f: a[0].reshape((shards,) + a.shape[-2:])
+        for f, a in lv._asdict().items() if a is not None})
+
+
+def _assert_inert(lv, s, rows=0):
+    """Shard ``s``'s schedule rows past ``rows`` fold nothing."""
+    assert (lv.idx[s, rows:] == -1).all()
+    assert (lv.weights[s, rows:] == 0).all()
+    assert (lv.src[s, rows:] == lv.payloads.shape[1]).all()
+
+
 @pytest.mark.parametrize("shards", [1, 2, 3, 4, 8])
 def test_shard_schedule_is_a_partition(shards):
     """Every live batch lands exactly once, on the shard that owns its
-    worker ring; padding rows/shards are inert."""
+    worker ring, with its own payload rows and no other shard's;
+    padding rows/shards are inert."""
     sched = _demuxed_schedule()
-    idx, w, pk, _, _ = ec.shard_schedule(sched, shards)
-    assert idx.shape[0] == shards
-    # live (slot, weight) entries are conserved: multiset of scheduled
-    # arrivals is identical before and after the demux
-    def arrivals(i2, w2):
-        m = i2 >= 0
-        return sorted(zip(i2[m].ravel().tolist(), w2[m].ravel().tolist()))
-    flat_idx = idx.reshape(-1, idx.shape[-1])
-    flat_w = w.reshape(-1, w.shape[-1])
-    assert arrivals(flat_idx, flat_w) == arrivals(sched.idx, sched.weights)
-    # ring ownership: per-shard batches only come from workers mapped to
-    # that shard (match rows back by content)
+    lv = _shard_leaves(sched, shards)
+    assert lv.idx.shape[0] == shards
+    R = lv.idx.shape[1]
+    assert lv.payloads.shape == (shards, R * 7, 48)
+    dense = expand(sched.payloads, sched.src)
     live = sched.workers[:sched.n_batches]
+    placed = 0
     for s in range(shards):
-        for r in range(idx.shape[1]):
-            if (idx[s, r] >= 0).any():
-                src = np.nonzero((sched.idx == idx[s, r]).all(1))[0]
-                assert any(live[i] % shards == s for i in src
-                           if i < sched.n_batches)
-    # payload rows ride with their batch
-    total_pk = pk.reshape(-1, pk.shape[-2], pk.shape[-1]).sum(axis=0)
-    np.testing.assert_allclose(total_pk.sum(),
-                               ec.expand_payloads(sched)[:sched.n_batches]
-                               .sum(),
-                               rtol=1e-6)
+        # ring ownership: shard s folds the batches of the rings mapped
+        # to it, whole and in drain order
+        own = np.nonzero(live % shards == s)[0]
+        r = len(own)
+        np.testing.assert_array_equal(lv.idx[s, :r], sched.idx[own])
+        np.testing.assert_array_equal(lv.weights[s, :r],
+                                      sched.weights[own])
+        # payload rows ride with their batch: the shard's own rows, then
+        # a zero tail
+        got = expand(lv.payloads[s], lv.src[s])
+        assert got[:r].tobytes() == dense[own].tobytes()
+        n_rows = int((lv.src[s] < R * 7).sum())
+        assert n_rows == int((sched.idx[own] >= 0).sum())
+        assert not lv.payloads[s, n_rows:].any()
+        _assert_inert(lv, s, r)
+        placed += r
+    assert placed == sched.n_batches
 
 
 def test_shard_schedule_more_shards_than_workers():
     """shards > n_workers leaves the excess shards inert (the effective
     parallelism floor documented on EngineConfig.shards)."""
     sched = _demuxed_schedule(n_workers=2)
-    idx, w, pk, _, _ = ec.shard_schedule(sched, 8)
+    lv = _shard_leaves(sched, 8, n_workers=2)
     for s in range(2, 8):
-        assert (idx[s] == -1).all() and (w[s] == 0).all()
+        _assert_inert(lv, s)
+        assert not lv.payloads[s].any()
 
 
 def test_shard_schedule_empty_round():
     sched = ec.build_drain_schedule(
         np.zeros(0, np.int32), np.zeros(0, np.float32),
         np.zeros((0, 16), np.float32), n_workers=3, ring_capacity=4)
-    idx, w, pk, _, _ = ec.shard_schedule(sched, 4)
-    assert (idx == -1).all() and (w == 0).all() and (pk == 0).all()
+    lv = _shard_leaves(sched, 4, n_workers=3, cap=4, payload=16)
+    for s in range(4):
+        _assert_inert(lv, s)
+    assert not lv.payloads.any()
 
 
 # ---------------------------------------------------------------------------
@@ -342,3 +367,79 @@ def test_real_mesh_parity_subprocess():
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "MESH_PARITY_OK" in out.stdout
+
+
+@pytest.mark.skipif(jax.device_count() >= 8,
+                    reason="suite already runs on a real 8-device mesh")
+def test_real_mesh_q8_and_async_parity_subprocess():
+    """The mesh fold serves the int8 wire and the async windows too: a
+    q8 round on ``shards=4`` and an async call on ``shards=4`` (f32 and
+    q8 wire, three emit windows and a residual) run over a *real*
+    ``('worker',)`` mesh, each bitwise its single-device result."""
+    prog = (
+        "import numpy as np, jax, jax.numpy as jnp\n"
+        "assert jax.device_count() == 8, jax.device_count()\n"
+        "from repro.core.aggregation import quantize_packets\n"
+        "from repro.core.packets import packetize\n"
+        "from repro.core.rounds import make_async_stream\n"
+        "from repro.core.server import (EngineConfig, make_uplink_stream,\n"
+        "                               run_async_engine, run_engine_round)\n"
+        "from repro.runtime.sharding import worker_mesh\n"
+        "assert worker_mesh(4) is not None\n"
+        "rng = np.random.default_rng(4)\n"
+        "K, P, W = 6, 256, 32\n"
+        "flats = jnp.asarray(rng.integers(-8, 9, (K, P))\n"
+        "                    .astype(np.float32))\n"
+        "pk = jax.vmap(lambda f: packetize(f, W))(flats)\n"
+        "q, _ = quantize_packets(pk)\n"
+        "# power-of-two scales keep every dequantized sum exact in f32\n"
+        "sc = jnp.asarray(2.0 ** rng.integers(-3, 1, q.shape[:2]),\n"
+        "                 jnp.float32)\n"
+        "prev = jnp.asarray(rng.integers(-8, 9, P).astype(np.float32))\n"
+        "kw = dict(n_clients=K, n_params=P, payload=W, ring_capacity=4,\n"
+        "          n_workers=4, compile=True)\n"
+        "ev, _ = make_uplink_stream(rng, q, scales=sc, loss_rate=0.2,\n"
+        "                           dup_rate=0.3)\n"
+        "for mode in ('exact', 'approx'):\n"
+        "    base = run_engine_round(EngineConfig(mode=mode, **kw), None,\n"
+        "                            prev, ev)\n"
+        "    got = run_engine_round(EngineConfig(mode=mode, shards=4,\n"
+        "                                        **kw), None, prev, ev)\n"
+        "    for a, b in ((base.new_global, got.new_global),\n"
+        "                 (base.counts, got.counts)):\n"
+        "        np.testing.assert_array_equal(np.asarray(a),\n"
+        "                                      np.asarray(b))\n"
+        "for wire in ('f32', 'q8'):\n"
+        "    events = []\n"
+        "    for _ in range(3):\n"
+        "        sel = rng.random(K) < 0.9\n"
+        "        ver = rng.integers(0, 3, K)\n"
+        "        ev, _ = make_async_stream(\n"
+        "            rng, pk if wire == 'f32' else q, sel, ver,\n"
+        "            loss_rate=0.15, dup_rate=0.1,\n"
+        "            scales=None if wire == 'f32' else sc)\n"
+        "        events += ev\n"
+        "    a = run_async_engine(EngineConfig(buffer_size=4, **kw),\n"
+        "                         events, prev)\n"
+        "    b = run_async_engine(EngineConfig(buffer_size=4, shards=4,\n"
+        "                                      **kw), events, prev)\n"
+        "    assert a.stats.emits >= 2, a.stats.emits\n"
+        "    for x, y in ((a.globals_, b.globals_),\n"
+        "                 (a.emit_counts, b.emit_counts),\n"
+        "                 (a.state.total, b.state.total),\n"
+        "                 (a.state.counts, b.state.counts),\n"
+        "                 (a.state.global_, b.state.global_)):\n"
+        "        np.testing.assert_array_equal(np.asarray(x),\n"
+        "                                      np.asarray(y))\n"
+        "print('MESH_Q8_ASYNC_PARITY_OK')\n")
+    env = dict(os.environ,
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
+                          " --xla_force_host_platform_device_count=8"),
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "MESH_Q8_ASYNC_PARITY_OK" in out.stdout

@@ -201,8 +201,8 @@ def test_fold_block_visits_counts_the_grid_steps_that_accumulate(
     every row."""
     import jax
 
-    from repro.core.engine_compiled import (build_drain_schedule,
-                                            expand_payloads)
+    from _drain_reference import expand
+    from repro.core.engine_compiled import build_drain_schedule
 
     rng = np.random.default_rng(16)
     s, k, w = 1_088, 2, 8
@@ -226,7 +226,7 @@ def test_fold_block_visits_counts_the_grid_steps_that_accumulate(
     monkeypatch.setattr(ps, "_scatter_accum_body", counting_body)
     acc, _ = ps.packet_scatter_accum_scan(
         jnp.asarray(sched.idx), jnp.asarray(sched.weights),
-        jnp.asarray(expand_payloads(sched)), jnp.zeros((s, w)),
+        jnp.asarray(expand(sched.payloads, sched.src)), jnp.zeros((s, w)),
         jnp.zeros((s, 1)), use_pallas=True, interpret=True)
     acc.block_until_ready()
     jax.effects_barrier()
