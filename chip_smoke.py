@@ -28,7 +28,9 @@ Each phase prints one JSON line: its first call's seconds, the backend
 compile seconds and persistent-cache hits within that call, the warm
 round's seconds, the error against its reference, and the share of the
 dense sweep's slot-block visits the fold grid makes on the round's
-drain schedule (before any shard split).  The last line is
+drain schedule (before any shard split), and the demux's host buffer
+pool so far: its leases and how many reused a pooled buffer
+(``engine_compiled.host_pool_stats``).  The last line is
 ``{"ok": true, "device": {...}}``.  Any mismatch or exception exits
 nonzero, and so does a run that finds no TPU or whose round does not
 hold the Pallas kernel (``tpu_custom_call``).
@@ -254,10 +256,12 @@ def emit(line: dict) -> None:
 
 
 def phase_line(phase, cfg, res, times, err, events, **extra):
+    from repro.core.engine_compiled import host_pool_stats
     return dict(phase=phase, n_params=cfg.n_params, payload=cfg.payload,
                 slots=cfg.n_slots, accepted=res.stats.data_enqueued,
                 **times, max_err=err,
-                fold_visit_share=visit_share(cfg, events), **extra)
+                fold_visit_share=visit_share(cfg, events),
+                host_pool=host_pool_stats(), **extra)
 
 
 def phase_a(s: Smoke):

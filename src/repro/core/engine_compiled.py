@@ -79,6 +79,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
+import threading
 from typing import Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -135,6 +137,82 @@ def _use_pallas(cfg: EngineConfig) -> bool:
     if cfg.scan_body == "jnp":
         return False
     return cfg.use_kernel and not _interpret()
+
+
+# ---------------------------------------------------------------------------
+# Host buffer pool: the demux's payload arrays, recycled across rounds
+# ---------------------------------------------------------------------------
+
+# idle buffers kept per (row width, dtype): an f32 round leases two (its
+# candidate stack and its shipped rows) while the previous round's
+# shipped rows may still be held by their transfer
+POOL_IDLE = 4
+# a new buffer is this fraction larger than the rows asked for, so a
+# round with a few more candidates than the last still fits
+_POOL_SLACK = 1 / 8
+
+
+def _refcounts(bufs: list) -> List[int]:
+    return [sys.getrefcount(b) for b in bufs]
+
+
+# the count of an array that only the pool's list holds, taken the way
+# ``_HostPool`` takes it
+_FREE_REFS = _refcounts([np.empty(0)])[0]
+
+
+class _HostPool:
+    """The payload buffers ``demux_events`` writes, kept across rounds:
+    the analogue of the paper's DPDK mempool, whose mbufs are allocated
+    once and recycled by refcount.  Large arrays made anew each round
+    are mapped, faulted in, zeroed and unmapped each round; a pooled
+    buffer's pages stay mapped.
+
+    ``lease`` returns a view of the first ``rows`` rows of a pooled
+    array of that width and dtype with at least that many rows (the
+    smallest that fits), allocating and keeping a new one only when
+    none is free.  A buffer is free when nothing outside the pool
+    references it: every holder of a lease (a ``DrainSchedule``, its
+    ``arrivals`` view, ``leaf_layout``'s views, JAX while a transfer
+    still reads it) keeps the owning array alive, and so its reference
+    count above the pool's own.  After each lease at most ``POOL_IDLE``
+    free buffers stay per width and dtype, the least recently leased
+    dropped first, so a server whose shapes drift does not grow.
+    """
+
+    def __init__(self):
+        self._bufs: List[np.ndarray] = []   # least recently leased first
+        self._lock = threading.Lock()
+        self.leases = 0
+        self.reused = 0
+
+    def lease(self, rows: int, width: int, dtype) -> np.ndarray:
+        key = (int(width), np.dtype(dtype))
+        with self._lock:
+            refs = _refcounts(self._bufs)
+            idle = [b for b, n in zip(self._bufs, refs)
+                    if n == _FREE_REFS and (b.shape[1], b.dtype) == key]
+            fits = [b for b in idle if len(b) >= rows]
+            self.leases += 1
+            self.reused += bool(fits)
+            buf = (min(fits, key=len) if fits else
+                   np.empty((rows + int(rows * _POOL_SLACK), key[0]), key[1]))
+            spare = [b for b in idle if b is not buf]
+            drop = {id(b) for b in spare[:max(0, len(spare) - POOL_IDLE)]}
+            self._bufs = [b for b in self._bufs
+                          if b is not buf and id(b) not in drop] + [buf]
+            return buf[:rows]
+
+
+_POOL = _HostPool()
+
+
+def host_pool_stats() -> dict:
+    """The demux's buffer pool: leases made so far, how many of them
+    reused a pooled buffer (the rest allocated one), and the buffers it
+    keeps, leased or idle."""
+    return dict(leases=_POOL.leases, reused=_POOL.reused,
+                kept=len(_POOL._bufs))
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +320,8 @@ def _drain_layout(slots: np.ndarray, weights: np.ndarray, width: int, *,
     """``build_drain_schedule`` less its payload copy: ``payloads``
     comes back with its zero tail and its first ``n`` rows unset, for
     the caller to fill with the accepted rows in arrival order, so each
-    row is copied once."""
+    row is copied once.  It is leased from the host buffer pool
+    (``_HostPool``), so its pages are not new each round."""
     n = int(slots.shape[0])
     B = ring_capacity + (-ring_capacity) % block_pkts
     pk_dtype = np.float32 if scales is None else np.int8
@@ -283,7 +362,7 @@ def _drain_layout(slots: np.ndarray, weights: np.ndarray, width: int, *,
     rows_out = n_rows * ring_capacity
     src = np.full((n_rows, B), rows_out, np.int32)
     src[row, col] = np.arange(n, dtype=np.int32)
-    pk = np.empty((rows_out, width), pk_dtype)
+    pk = _POOL.lease(rows_out, width, pk_dtype)
     pk[n:] = 0
     sc = None
     if scales is not None:
@@ -495,7 +574,10 @@ def demux_events(cfg: EngineConfig, events: Iterable,
     The call runs in the ``fl.demux`` span, tiled by its phases:
     ``fl.demux.scan`` (the per-event pass), ``fl.demux.gate`` (the numpy
     FSM gate, deadline cut, malformed check and dedup),
-    ``fl.demux.stack`` (the payload stacks) and ``fl.demux.schedule``.
+    ``fl.demux.stack`` (the payload stacks, written into buffers leased
+    from the host pool; the round's last such span carries the pool's
+    running ``pool_leases`` and ``pool_reused`` counts) and
+    ``fl.demux.schedule``.
     """
     K, n_slots = cfg.n_clients, cfg.n_slots
     wts = (np.ones(K, np.float32) if weights is None
@@ -593,7 +675,10 @@ def demux_events(cfg: EngineConfig, events: Iterable,
             [p is not None for p in d_pay], bool))[0]
     if len(fi):
         with _span("fl.demux.stack"):
-            f32_stack = np.asarray([d_pay[i] for i in fi], np.float32)
+            # the candidate rows, copied once into a pooled buffer
+            f32_stack = _POOL.lease(len(fi), np.shape(d_pay[fi[0]])[0],
+                                    np.float32)
+            f32_stack[...] = [d_pay[i] for i in fi]
     with _span("fl.demux.gate"):
         if len(fi):
             bad[fi] = ~np.isfinite(f32_stack).all(axis=1)
@@ -622,12 +707,14 @@ def demux_events(cfg: EngineConfig, events: Iterable,
             ring_assign=cfg.ring_assign,
             scales=sc_arr[acc_rows] if q8_round else None,
             clients=dc[acc_rows].astype(np.int32))
-    with _span("fl.demux.stack"):
+    with _span("fl.demux.stack", pool_leases=_POOL.leases,
+               pool_reused=_POOL.reused):
         # copy only the *accepted* payload rows, once, straight into the
         # shipped array: dropped DATA may legally carry no payload (the
         # eager rx phase-drops before its assert)
         pay = sched.payloads[:len(acc_rows)]
         if q8_round:
+            # numpy fills the view from the list directly, no temporary
             pay[...] = [d_pay[i] for i in acc_rows]
         elif n_q8:
             # mixed f32/q8 round: correctness fallback — decode the q8 rows
@@ -644,8 +731,6 @@ def demux_events(cfg: EngineConfig, events: Iterable,
             # mode "raise" would buffer the gather before the copy)
             np.take(f32_stack, pos_in_f32[acc_rows], axis=0, out=pay,
                     mode="wrap")
-        # freeing the candidate stack is part of the stacking cost
-        del f32_stack
     stats.batches_drained = sched.n_batches
     return sched, stats, up
 

@@ -8,17 +8,24 @@ from repro.core import engine_compiled as ec
 from repro.core.protocol import Kind
 
 
-def accepted_rows(events, decode: bool = False):
-    """(slots, rows) of the first copy of each (client, slot) DATA
-    packet, in arrival order: the accepted arrivals of a
+def first_copies(events):
+    """The first copy of each (client, slot) DATA packet, as ``(Packet,
+    payload)`` in arrival order: the accepted arrivals of a
     ``make_uplink_stream`` round, whose STARTs precede every DATA and
-    whose ENDs follow it.  ``decode`` turns q8 rows into f32 as the
-    mixed-wire fallback does."""
-    seen, slots, rows = set(), [], []
+    whose ENDs follow it."""
+    seen = set()
     for pkt, pay in events:
         if pkt.kind is not Kind.DATA or (pkt.client, pkt.index) in seen:
             continue
         seen.add((pkt.client, pkt.index))
+        yield pkt, pay
+
+
+def accepted_rows(events, decode: bool = False):
+    """(slots, rows) of ``first_copies(events)``.  ``decode`` turns q8
+    rows into f32 as the mixed-wire fallback does."""
+    slots, rows = [], []
+    for pkt, pay in first_copies(events):
         slots.append(pkt.index)
         row = np.asarray(pay)
         if decode:
@@ -26,6 +33,47 @@ def accepted_rows(events, decode: bool = False):
                    if pkt.wire_dtype != "f32" else row.astype(np.float32))
         rows.append(row)
     return np.asarray(slots, np.int64), np.stack(rows)
+
+
+def reference_schedule(cfg, events):
+    """``build_drain_schedule`` on the accepted arrivals of one
+    homogeneous-wire ``make_uplink_stream`` round, at unit weights."""
+    firsts = list(first_copies(events))
+    slots, rows = accepted_rows(events)
+    q8 = firsts[0][0].wire_dtype != "f32"
+    return ec.build_drain_schedule(
+        slots.astype(np.int32), np.ones(len(slots), np.float32), rows,
+        n_workers=cfg.n_workers, ring_capacity=cfg.ring_capacity,
+        ring_assign=cfg.ring_assign,
+        scales=(np.asarray([p.scale for p, _ in firsts], np.float32)
+                if q8 else None),
+        clients=np.asarray([p.client for p, _ in firsts], np.int32))
+
+
+SCHEDULE_ARRAYS = ("idx", "weights", "payloads", "src", "workers",
+                   "scales", "clients")
+
+
+def schedule_copy(sched) -> dict:
+    """A schedule's counts and arrays, copied out of its buffers."""
+    out = {f: (None if getattr(sched, f) is None
+               else np.array(getattr(sched, f))) for f in SCHEDULE_ARRAYS}
+    out.update(n_batches=sched.n_batches, n_packets=sched.n_packets)
+    return out
+
+
+def assert_schedules_equal(got, want):
+    """Two schedules, or their ``schedule_copy``, are bitwise equal."""
+    got, want = (s if isinstance(s, dict) else schedule_copy(s)
+                 for s in (got, want))
+    assert got.keys() == want.keys()
+    for f, a in got.items():
+        b = want[f]
+        if isinstance(a, np.ndarray):
+            assert (a.shape, a.dtype) == (b.shape, b.dtype), f
+            assert a.tobytes() == b.tobytes(), f
+        else:
+            assert a == b, f
 
 
 def dense_payloads(slots, rows, *, n_workers: int, ring_capacity: int,

@@ -13,6 +13,9 @@ The load-bearing acceptance properties (ISSUE 3 + DESIGN.md §3):
 3. The round dispatch *donates* the (total, counts) accumulators — no
    fresh (N, W) buffer per drain/scan step.
 """
+import sys
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +23,8 @@ import pytest
 from _drain_reference import (accepted_rows, assert_expands_to,
                               dense_payloads)
 from _hypothesis_shim import given, settings, st
+from _pool_checks import (held_schedules_keep_their_rows,
+                          pool_rounds, pooled_rounds_match_reference)
 
 from repro.core import engine_compiled as ec
 from repro.core.aggregation import fused_round_step
@@ -423,3 +428,85 @@ def test_make_uplink_stream_vectorized_semantics():
         if p.kind == Kind.DATA:
             np.testing.assert_array_equal(np.asarray(pay),
                                           np.asarray(pk[p.client, p.index]))
+
+
+# ---------------------------------------------------------------------------
+# Host buffer pool: the demux's payload buffers recycled across rounds
+# ---------------------------------------------------------------------------
+
+def test_pooled_rounds_reuse_buffers_and_match_reference(monkeypatch):
+    pooled_rounds_match_reference(monkeypatch, "f32")
+
+
+def test_held_schedules_keep_their_rows():
+    held_schedules_keep_their_rows("f32")
+
+
+@pytest.mark.parametrize("wire", ["f32", "q8"])
+def test_overlapped_pooled_rounds_match_one_at_a_time(wire):
+    """``run_compiled_rounds`` demuxes round r+1 while round r is in
+    flight, not blocking between them; over rounds that recycle the
+    pool's buffers its results are bitwise the same rounds served one
+    at a time, so no transfer reads a buffer leased again."""
+    cfg, rounds = pool_rounds(5, wire=wire, seed=23)
+    before = ec.host_pool_stats()
+    overlapped = ec.run_compiled_rounds(
+        cfg, [(events, None, None) for events, _ in rounds],
+        jnp.zeros(1000, jnp.float32))
+    assert ec.host_pool_stats()["reused"] > before["reused"]
+    g = jnp.zeros(1000, jnp.float32)
+    for (events, _), got in zip(rounds, overlapped):
+        want = ec.run_compiled_round(cfg, None, g, events)
+        _assert_rounds_equal(want, got)
+        assert got.stats == want.stats
+        g = want.new_global
+    assert len(overlapped) == 5
+
+
+def test_pool_keeps_a_bounded_number_of_idle_buffers():
+    """Eight schedules held at once need eight buffers; once they are
+    released, the next lease leaves at most ``POOL_IDLE`` idle ones of
+    that width and dtype besides its own."""
+    def schedule():
+        return ec.build_drain_schedule(
+            np.arange(20, dtype=np.int32), np.ones(20, np.float32),
+            np.ones((20, 23), np.float32), n_workers=2, ring_capacity=5)
+    kept = ec.host_pool_stats()["kept"]
+    held = [schedule() for _ in range(8)]
+    assert ec.host_pool_stats()["kept"] == kept + 8
+    del held
+    last = schedule()
+    assert ec.host_pool_stats()["kept"] == kept + ec.POOL_IDLE + 1
+    assert (last.payloads[:20] == 1).all()
+    assert not last.payloads[20:].any()
+
+
+def test_pool_leases_no_held_buffer_across_threads():
+    """Threads demuxing at once (more threads than cores, a short
+    switch interval) never lease a buffer another thread holds: each
+    held schedule's shipped rows stay its own after every yield."""
+    def worker(k, failures):
+        slots = np.arange(40, dtype=np.int32)
+        rows = np.full((40, 16), k, np.float32)
+        for _ in range(300):
+            sched = ec.build_drain_schedule(
+                slots, np.ones(40, np.float32), rows, n_workers=3,
+                ring_capacity=7)
+            threading.Event().wait(0)
+            if not (sched.payloads[:40] == k).all():
+                failures.append(k)
+
+    failures = []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(k, failures))
+                   for k in range(32)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert failures == []

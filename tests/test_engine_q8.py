@@ -22,6 +22,8 @@ import numpy as np
 import pytest
 from _drain_reference import (accepted_rows, assert_expands_to,
                               dense_payloads)
+from _pool_checks import (held_schedules_keep_their_rows,
+                          pooled_rounds_match_reference)
 
 from repro.core import packets as pktmod
 from repro.core.aggregation import quantize_packets
@@ -330,3 +332,13 @@ def test_q8_deadline_and_dedup_semantics_unchanged():
         (sb.data_enqueued, sb.duplicates_dropped, sb.late_dropped,
          sb.stragglers_timed_out)
     assert sa.duplicates_dropped > 0 and sa.late_dropped > 0
+
+
+def test_q8_pooled_rounds_reuse_buffers_and_match_reference(monkeypatch):
+    """The q8 twin of the f32 pool test: a q8 round leases only its
+    shipped int8 rows, and reuses them from the third round on."""
+    pooled_rounds_match_reference(monkeypatch, "q8")
+
+
+def test_q8_held_schedules_keep_their_rows():
+    held_schedules_keep_their_rows("q8")

@@ -198,6 +198,64 @@ def test_clean_retransmission_after_malformed_accepted():
 
 
 # ---------------------------------------------------------------------------
+# the recycled candidate buffer: poisoned rows, then a smaller clean round
+# ---------------------------------------------------------------------------
+
+def _poison_around_first_copy(events, before, after):
+    """A NaN copy of the (client, slot) DATA packets in ``before`` ahead
+    of their first clean copy, and one of those in ``after`` right
+    behind it (a poisoned duplicate of an accepted row)."""
+    out, seen = [], set()
+    for packet, payload in events:
+        key = (packet.client, packet.index)
+        first = packet.kind is Kind.DATA and key not in seen
+        bad = None
+        if first and key in before | after:
+            bad = np.asarray(payload).copy()
+            bad[1] = np.nan
+            bad = (packet, jnp.asarray(bad))
+        if bad is not None and key in before:
+            out.append(bad)
+        out.append((packet, payload))
+        if bad is not None and key in after:
+            out.append(bad)
+        if packet.kind is Kind.DATA:
+            seen.add(key)
+    return out
+
+
+def test_recycled_candidate_buffer_leaks_no_stale_row():
+    """Round 1 stacks poisoned candidates; round 2, clean and with fewer
+    candidates, stacks into a recycled buffer that still holds round
+    1's rows past its own.  Both rounds' counters and globals equal the
+    eager engine's, and round 2 counts nothing malformed."""
+    from repro.core import engine_compiled as ec
+    rng, flats, prev, pk = _round_inputs(17)
+    events, _ = make_uplink_stream(rng, pk, dup_rate=0.3)
+    first = _poison_around_first_copy(events, {(0, 0), (3, 2)},
+                                      {(1, 4), (5, 9)})
+    flats2 = flats[::-1] + 1.0
+    pk2 = jax.vmap(lambda f: packetize(f, W))(flats2)
+    second, _ = make_uplink_stream(rng, pk2, loss_rate=0.4)
+    assert (sum(p.kind is Kind.DATA for p, _ in second)
+            < sum(p.kind is Kind.DATA for p, _ in first))
+    g = {c: prev for c in (False, True)}
+    for r, (f, events) in enumerate(((flats, first), (flats2, second))):
+        res = {}
+        for compile_ in (False, True):
+            before = ec.host_pool_stats()["reused"]
+            res[compile_] = run_engine_round(_cfg(compile=compile_), f,
+                                             g[compile_], events)
+            g[compile_] = res[compile_].new_global
+        _assert_rounds_equal(res[False], res[True])
+        assert res[False].stats == res[True].stats
+        s = res[True].stats
+        assert s.malformed_dropped == (4 if r == 0 else 0)
+        assert np.isfinite(np.asarray(g[True])).all()
+    assert ec.host_pool_stats()["reused"] > before
+
+
+# ---------------------------------------------------------------------------
 # conservation identity with the new bucket
 # ---------------------------------------------------------------------------
 
